@@ -8,8 +8,7 @@ while_loop over an N-device (batch, nonce) mesh — the path that wins the
   XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \\
       python benchmarks/multichip.py --devices 8
 
-``--ab`` runs the shard_map-FREE fan A/B (parallel/fan_search.py, the path
-this image's jax 0.4.37 can actually execute): single-device scan of a
+``--ab`` runs the pmap fan A/B (parallel/fan_search.py): single-device scan of a
 span S vs the same span fanned across N devices (S/N per device) at a
 sweep of fan widths — matched spans, so the ratio is the device-parallel
 speedup. On virtual CPU devices the ceiling is min(devices, cpu_cores):
@@ -197,14 +196,13 @@ def ab(n_devices: int, span: int, reps: int, out_path: str = "") -> dict:
 
     Single device scans ``span`` nonces per rep; a fan of w devices scans
     the same ``span`` with ``span/w`` per device. Wall-clock ratio =
-    aggregate device-parallel speedup. Runs on ANY jax this project
-    supports (pmap, parallel/fan_search.py) — no shard_map needed.
+    aggregate device-parallel speedup (pmap, parallel/fan_search.py).
     """
     import jax
     import jax.numpy as jnp
 
     from tpu_dpow.ops import search
-    from tpu_dpow.parallel import fan_search_chunk_batch, has_shard_map
+    from tpu_dpow.parallel import fan_search_chunk_batch
 
     devices = jax.devices()[:n_devices]
     if len(devices) < n_devices:
@@ -289,7 +287,6 @@ def ab(n_devices: int, span: int, reps: int, out_path: str = "") -> dict:
             "on this box; near-linear device scaling is only observable "
             "with >= devices free cores or real chips"
         ),
-        "has_shard_map": has_shard_map(),
     }
     print(json.dumps(result, indent=2))
     if out_path:
@@ -310,8 +307,7 @@ if __name__ == "__main__":
                    "lengths (the 8-chip projection's measured components)")
     p.add_argument("--ab", action="store_true",
                    help="single-device vs device-fanned A/B at matched "
-                   "spans via the shard_map-free pmap fan (runs on this "
-                   "image's jax)")
+                   "spans via the pmap fan")
     p.add_argument("--span", type=int, default=1 << 20,
                    help="total nonces per row per launch for --ab (split "
                    "across the fan; large spans measure scan, not dispatch)")
@@ -321,20 +317,7 @@ if __name__ == "__main__":
     args = p.parse_args()
     if args.ab:
         ab(args.devices, args.span, args.reps, args.out)
+    elif args.sweep:
+        sweep(args.devices, args.reps)
     else:
-        # The shard_map modes need jax >= 0.6; fail with the capability
-        # story instead of an AttributeError from deep inside the launch.
-        import jax as _jax
-
-        from tpu_dpow.parallel import has_shard_map
-
-        if not has_shard_map():
-            raise SystemExit(
-                f"this jax ({_jax.__version__}) has no jax.shard_map — the "
-                "mesh modes cannot run; use --ab (the shard_map-free pmap "
-                "fan A/B) instead"
-            )
-        if args.sweep:
-            sweep(args.devices, args.reps)
-        else:
-            run(args.devices, args.batch_shards, args.chunk_per_shard, args.reps)
+        run(args.devices, args.batch_shards, args.chunk_per_shard, args.reps)

@@ -19,8 +19,7 @@ this benchmark answers on real hardware:
 
 Adopt the rolled group in ops/pallas_kernel.py::_search_core only if the
 on-chip H/s stays within a few percent of the unrolled body — the warmup
-window (cold-start flood at 6.7 req/s for ~2 min through a tunnel) then
-shrinks ~5x.
+window (the cold-start compile of every launch shape) then shrinks ~5x.
 """
 
 from __future__ import annotations
@@ -28,9 +27,8 @@ from __future__ import annotations
 import os
 
 # Compile cost is a MEASURED OUTPUT here (compile_s below), so this bench
-# must see real Mosaic compiles, not persistent-cache loads — opt out
-# before _bootstrap wires the shared cache up.
-os.environ.setdefault("TPU_DPOW_NO_COMPILE_CACHE", "1")
+# must see real Mosaic compiles, not persistent-cache loads.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import _bootstrap  # noqa: F401,E402  (repo root on sys.path)
 

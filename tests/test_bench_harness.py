@@ -1,8 +1,6 @@
-"""bench.py harness contract — the file the DRIVER parses for the round's
-perf artifact. Two rounds lost their TPU evidence to harness edge cases
-(rc=1 init crash, timeout->premature CPU fallback), so the child-process
-plumbing is pinned here with stub children: JSON extraction from noisy
-stdout, failure labeling, timeout kills, and the attempt-log format."""
+"""bench.py harness contract: the child-process plumbing is pinned here
+with stub children — JSON extraction from noisy stdout, failure labeling,
+timeout kills — and the result line's fields."""
 
 import json
 import os
@@ -38,7 +36,7 @@ def test_run_child_parses_last_json_line_from_noisy_stdout(stub_child):
         print("trailing log line")
         print(json.dumps({"metric": "m", "value": 42.5, "unit": "H/s"}))
     """)
-    out, why = bench._run_child("tpu", timeout=30)
+    out, why = bench._run_child(timeout=30)
     assert why == ""
     assert out == {"metric": "m", "value": 42.5, "unit": "H/s"}
 
@@ -49,7 +47,7 @@ def test_run_child_labels_crash_with_stderr_tail(stub_child):
         print("RuntimeError: UNAVAILABLE: TPU backend setup", file=sys.stderr)
         sys.exit(1)
     """)
-    out, why = bench._run_child("tpu", timeout=30)
+    out, why = bench._run_child(timeout=30)
     assert out is None
     assert why.startswith("rc=1")
     assert "UNAVAILABLE" in why
@@ -59,7 +57,7 @@ def test_run_child_kills_on_timeout(stub_child):
     stub_child("""
         time.sleep(60)
     """)
-    out, why = bench._run_child("tpu", timeout=1)
+    out, why = bench._run_child(timeout=1)
     assert out is None
     assert why.startswith("timeout>")
     assert not bench._children  # the timed-out child was reaped
@@ -69,7 +67,7 @@ def test_run_child_flags_missing_json(stub_child):
     stub_child("""
         print("no json here at all")
     """)
-    out, why = bench._run_child("tpu", timeout=30)
+    out, why = bench._run_child(timeout=30)
     assert out is None
     assert "no JSON result line" in why
 

@@ -43,7 +43,6 @@ from tpu_dpow.resilience import (
 from tpu_dpow.resilience.devfault import DeviceFaultDomains
 from tpu_dpow.utils import nanocrypto as nc
 
-from conftest import requires_fan_devices
 
 RNG = np.random.default_rng(12)
 EASY = 0xFFF0000000000000
@@ -160,7 +159,6 @@ def test_faulty_device_seam_maps_physical_index_and_releases():
 # -- the chaos acceptance test ---------------------------------------------
 
 
-@requires_fan_devices
 def test_hung_device_evacuation_quarantine_and_probe_readmission():
     """THE acceptance scenario (FakeClock, 8-device fan, persistent):
     device 3 hangs mid-launch at its control poll → the watchdog declares
@@ -519,7 +517,6 @@ def test_chunked_backstop_evacuates_hung_launch():
 # -- the operator-facing demo ----------------------------------------------
 
 
-@requires_fan_devices
 def test_chaos_demo_device_scenario_completes():
     """scripts/chaos_demo.py's device walkthrough (hang -> evacuate ->
     solve -> probe re-admission) must complete with its invariants, like

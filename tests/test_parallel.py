@@ -7,10 +7,8 @@ on-device reduction instead of the Redis SETNX lock (reference
 server/dpow_server.py:138).
 
 TWO gang implementations share the contract and run the same assertions
-(parametrized below): the shard_map mesh (parallel/mesh_search.py, jax >=
-0.6 — capability-gated) and the pmap fan (parallel/fan_search.py — the
-shard_map-FREE path that runs on this image's jax 0.4.37, so the
-device-parallel suite executes in tier-1 instead of skipping).
+(parametrized below): the shard_map mesh (parallel/mesh_search.py) and the
+pmap fan (parallel/fan_search.py).
 """
 
 import hashlib
@@ -28,7 +26,6 @@ from tpu_dpow.parallel import (
     expected_steps,
     fan_search_chunk_batch,
     fan_search_run,
-    has_shard_map,
     make_mesh,
     replicate_params,
     sharded_search_chunk_batch,
@@ -36,16 +33,12 @@ from tpu_dpow.parallel import (
 )
 from tpu_dpow.utils import nanocrypto as nc
 
-from conftest import requires_fan_devices, requires_shard_map
-
 CHUNK = 256  # tiny per-shard windows: tests stay fast on CPU
 
-#: Each gang test runs once per implementation. The fan runs everywhere
-#: (this image's tier-1 included); the shard_map mesh variant is gated on
-#: the jax >= 0.6 capability.
+#: Each gang test runs once per implementation.
 GANG_IMPLS = [
-    pytest.param("fan", id="fan", marks=requires_fan_devices),
-    pytest.param("shard_map", id="shard_map", marks=requires_shard_map),
+    pytest.param("fan", id="fan"),
+    pytest.param("shard_map", id="shard_map"),
 ]
 
 
@@ -118,19 +111,18 @@ def test_mesh_shape():
     assert m2.shape[NONCE_AXIS] == len(jax.devices()) // 4
 
 
-def test_capability_probe_gates_engine_mesh_path():
-    """The engine's mesh_devices gate must agree with has_shard_map():
-    where the probe says no, constructing a mesh backend fails AT
-    CONSTRUCTION with the capability story (not an AttributeError from the
-    first launch); where it says yes, construction succeeds."""
+def test_engine_mesh_spans_distinct_local_devices():
+    """mesh_devices=N builds the gang over N DISTINCT local devices (not N
+    copies of device 0), and asking for more than exist fails at
+    construction."""
     from tpu_dpow.backend import WorkError
     from tpu_dpow.backend.jax_backend import JaxWorkBackend
 
-    if has_shard_map():
-        assert JaxWorkBackend(kernel="xla", mesh_devices=1).mesh is not None
-    else:
-        with pytest.raises(WorkError, match="shard_map"):
-            JaxWorkBackend(kernel="xla", mesh_devices=1)
+    b = JaxWorkBackend(kernel="xla", mesh_devices=4)
+    assert sorted(d.id for d in b.mesh.devices.flat) == [
+        d.id for d in jax.local_devices()[:4]]
+    with pytest.raises(WorkError, match="local devices"):
+        JaxWorkBackend(kernel="xla", mesh_devices=len(jax.local_devices()) + 1)
 
 
 def test_finds_planted_nonce_in_any_shard(gang):
@@ -322,7 +314,6 @@ def test_gang_run_active_mask_skips_padding(gang):
     assert int(lo[1]) == 0xFFFFFFFF and int(hi[1]) == 0xFFFFFFFF
 
 
-@requires_fan_devices
 def test_fan_matches_shard_map_contract_on_partial_width(gang):
     """A 4-device gang (half the complement) still tiles its window with no
     gaps: a nonce planted in the LAST device's sub-range is found. Pins the
@@ -374,7 +365,6 @@ def test_arrange_by_host_rejects_ragged_slice():
         arrange_by_host([_StubDev(0, 0), _StubDev(1, 0), _StubDev(2, 1)])
 
 
-@requires_shard_map
 def test_multihost_mesh_single_process_runs_search():
     """With one process the multihost mesh is (1, n_local) — and the ganged
     search must run on it exactly as on make_mesh's latency mode."""

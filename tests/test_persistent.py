@@ -40,7 +40,6 @@ from tpu_dpow.ops import runloop, search
 from tpu_dpow.resilience.clock import FakeClock
 from tpu_dpow.utils import nanocrypto as nc
 
-from conftest import requires_fan_devices
 
 RNG = np.random.default_rng(10)
 EASY = 0xFFF0000000000000
@@ -245,7 +244,7 @@ def test_poll_to_effect_latency_rides_injectable_clock():
 #: threading and stay capability-gated with the rest of the mesh suite.
 ENGINE_IMPLS = [
     pytest.param("plain", id="plain"),
-    pytest.param("fan", id="fan", marks=requires_fan_devices),
+    pytest.param("fan", id="fan"),
 ]
 
 
@@ -529,17 +528,9 @@ def test_persistent_refuses_the_shard_map_mesh():
     independent per-device control polls inside one collective program can
     diverge the replicated while_loop into a deadlock. The fan is the
     supported persistent multi-chip path (mesh_search.py docstring has the
-    jax >= 0.6 broadcast follow-up)."""
-    from tpu_dpow.parallel import has_shard_map
-
-    if has_shard_map():
-        with pytest.raises(WorkError, match="persistent"):
-            JaxWorkBackend(kernel="xla", run_mode="persistent", mesh_devices=1)
-    else:
-        # On this jax the mesh is refused earlier (no shard_map at all);
-        # the persistent gate must still hold where the mesh exists.
-        with pytest.raises(WorkError):
-            JaxWorkBackend(kernel="xla", run_mode="persistent", mesh_devices=1)
+    broadcast follow-up)."""
+    with pytest.raises(WorkError, match="persistent"):
+        JaxWorkBackend(kernel="xla", run_mode="persistent", mesh_devices=1)
 
 
 def test_persistent_dedup_and_concurrent_batch():

@@ -1,4 +1,4 @@
-"""summarize_capture.py contract — the tool that turns BENCH_latency.json
+"""summarize_capture.py contract — the tool that turns a capture artifact
 into the round's PASS/FAIL gap list. A bug here misreports the evidence
 the whole round exists to produce (a false PASS hides a regression; a
 false FAIL sends the next session chasing a ghost), so the criteria
@@ -190,24 +190,6 @@ def test_recapture_supersedes_invalidation_fingerprint(tmp_path):
     _, rows = summarize(tmp_path, {"latency_mesh1": rec},
                         ["--mark", "r4", "--invalidated", str(inv)])
     assert rows["latency_mesh1"][0] == "PASS"
-
-
-def test_repo_invalidation_list_covers_the_r4_mesh1_record():
-    # Pin the actual hole closed: the repo's own invalidated.json must match
-    # the real r4 latency_mesh1 record still sitting in BENCH_latency.json.
-    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-    try:
-        import summarize_capture as sc
-    finally:
-        sys.path.pop(0)
-    entries = sc.load_invalidations()
-    with open(os.path.join(REPO, "BENCH_latency.json")) as f:
-        data = json.load(f)
-    rec = data.get("latency_mesh1")
-    if not (isinstance(rec, dict) and rec.get("mark") == "r4"
-            and sc.res(rec).get("p50_ms") == 183.6):
-        return  # superseded by a real re-capture: nothing left to disavow
-    assert sc.invalidation_reason("latency_mesh1", rec, entries) is not None
 
 
 def test_unreadable_invalidation_list_fails_closed(tmp_path):

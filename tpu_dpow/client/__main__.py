@@ -16,20 +16,16 @@ from .config import parse_args
 
 
 async def amain(argv=None) -> None:
-    from ..utils import honor_jax_platforms_env
+    from ..utils import enable_compilation_cache, maybe_init_distributed
 
-    honor_jax_platforms_env()
-    from ..utils import maybe_init_distributed
-
+    # Before the engine's first compile: a restarted worker reloads its
+    # warm ladder from the cache instead of recompiling it.
+    enable_compilation_cache()
     maybe_init_distributed()
     import socket
 
     config = parse_args(argv)
     get_logger("tpu_dpow.client", file_path=config.log_file)
-    if config.compilation_cache:
-        from ..utils import enable_compilation_cache
-
-        enable_compilation_cache(config.compilation_cache)
     # client_id must be stable across restarts (durable session: offline
     # QoS-1 cancel/client replay) but UNIQUE per worker — payout address
     # alone collides when a fleet shares one payout, and the broker's

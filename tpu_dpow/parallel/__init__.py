@@ -2,10 +2,8 @@
 election. See mesh_search for the design rationale.
 
 Two gang implementations share one contract: the shard_map mesh
-(mesh_search, jax >= 0.6 — ``has_shard_map`` gates it) and the pmap fan
-(fan_search — runs on every jax this project supports, including this
-image's 0.4.37). Engines pick the fan by default and keep the mesh as the
-capability-gated fast path."""
+(mesh_search) and the pmap fan (fan_search). ROADMAP C3 weighs keeping
+only one."""
 
 from .fan_search import (  # noqa: F401
     FAN_AXIS,
@@ -14,7 +12,6 @@ from .fan_search import (  # noqa: F401
     fan_search_devices,
     fan_search_run,
     fan_search_run_controlled,
-    has_shard_map,
 )
 from .mesh_search import (  # noqa: F401
     BATCH_AXIS,

@@ -1,10 +1,6 @@
-"""Device-parallel nonce search WITHOUT shard_map: a pmap fan-out.
+"""Device-parallel nonce search as a pmap fan-out.
 
-The mesh gang (parallel/mesh_search.py) is built on ``jax.shard_map``, which
-was promoted out of jax.experimental in jax 0.6 — this image's jax (0.4.37)
-does not have it, so the only multi-chip path sat capability-skipped while
-MULTICHIP_r05 proved 8 local devices are addressable. This module is the
-shard_map-FREE twin built on primitives that exist on jax 0.4.37:
+The twin of the shard_map mesh gang (parallel/mesh_search.py), built on
 ``jax.pmap`` over ``jax.local_devices()`` with ``lax.axis_index`` range
 interleaving and a ``lax.pmin`` winner election.
 
@@ -28,10 +24,6 @@ attribution — backend/jax_backend.py's fan mode) use
 :func:`fan_search_devices` instead: per-device base rows in, per-device
 local offsets out, no collective — the host elects the winner and keeps
 the attribution.
-
-The shard_map gang stays the preferred implementation where it exists
-(:func:`has_shard_map` gates it); on jax >= 0.6 both paths run and the
-mesh tests pin them against each other.
 """
 
 from __future__ import annotations
@@ -50,13 +42,6 @@ from ..ops.search import SENTINEL
 FAN_AXIS = "fan"
 
 _MASK64 = (1 << 64) - 1
-
-
-def has_shard_map() -> bool:
-    """True when this jax has the promoted ``jax.shard_map`` (>= 0.6) —
-    the mesh gang fast path. False routes multi-device work through the
-    pmap fan in this module."""
-    return hasattr(jax, "shard_map")
 
 
 def fan_devices(n: int = -1) -> List[jax.Device]:

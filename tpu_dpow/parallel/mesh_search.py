@@ -222,8 +222,7 @@ def sharded_search_run_controlled(
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """:func:`sharded_search_run` with a live control channel — the
-    PERSISTENT mesh launch (jax >= 0.6, capability-gated like the rest of
-    the shard_map path; the fan twin is
+    PERSISTENT mesh launch (the fan twin is
     ``parallel.fan_search.fan_search_run_controlled``).
 
     SPMD caveat (why the engine refuses mesh+persistent): on a REAL
@@ -233,8 +232,7 @@ def sharded_search_run_controlled(
     poll blocks, diverge in while_loop trip count, and deadlock the next
     collective. Safe on a one-device mesh (the gang-machinery A/B); the
     multi-device fix is pinning the poll to one device and broadcasting
-    (``io_callback(..., sharding=)``) — to be validated when a jax >= 0.6
-    image can actually run the mesh.
+    (``io_callback(..., sharding=)``), not yet validated on real chips.
 
     The loop structure is identical to :func:`sharded_search_run` — the
     while_loop sits OUTSIDE the shard_map and every window's ganged launch

@@ -26,9 +26,6 @@ from .config import parse_args
 
 
 async def amain(argv=None) -> None:
-    from ..utils import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     config = parse_args(argv)
     logger = get_logger("tpu_dpow.server", file_path=config.log_file, debug=config.debug)
 

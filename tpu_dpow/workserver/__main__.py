@@ -25,21 +25,13 @@ async def amain(argv=None) -> None:
     p.add_argument("--mesh_devices", type=int, default=0,
                    help="gang N local devices per hash; 0 = plain "
                    "single-device path (backend=jax)")
-    p.add_argument("--compilation_cache", default="",
-                   help="persistent XLA compilation cache dir ('' = off)")
     p.add_argument("--verbose", action="store_true")
     ns = p.parse_args(argv)
     logging.basicConfig(level=logging.DEBUG if ns.verbose else logging.INFO)
 
-    from ..utils import honor_jax_platforms_env
+    from ..utils import enable_compilation_cache, maybe_init_distributed
 
-    honor_jax_platforms_env()
-    if ns.compilation_cache:
-        from ..utils import enable_compilation_cache
-
-        enable_compilation_cache(ns.compilation_cache)
-    from ..utils import maybe_init_distributed
-
+    enable_compilation_cache()
     maybe_init_distributed()
 
     host, _, port_str = ns.listen.rpartition(":")
