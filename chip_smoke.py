@@ -238,6 +238,13 @@ async def drive(session, server: ServerChild, name: str) -> None:
             f"max {max(lat) * 1e3:.1f} ms")
 
 
+def engine_scan_totals(backend) -> tuple:
+    """(dpow_engine_hashes_total, dpow_engine_device_seconds_sum) of the
+    jax engine, as its registry holds them now."""
+    device = backend._m_device_seconds.collect().get(("jax",), {})
+    return backend._m_hashes.value("jax"), device.get("sum", 0.0)
+
+
 async def serve_once(name: str, extra: list, check=None) -> None:
     """One server child + one worker: warm, serve the request list, check."""
     import aiohttp
@@ -252,9 +259,11 @@ async def serve_once(name: str, extra: list, check=None) -> None:
             ) as session:
                 await wait_up(server, session)
                 client, backend = await start_worker(server, name, extra)
+                scanned0 = engine_scan_totals(backend)
                 await drive(session, server, name)
-            log(f"[{name}] engine H/s gauge (last applied launch): "
-                f"{backend._m_hash_rate.value('jax'):.4g}")
+            hashes, device_s = (b - a for a, b in zip(scanned0, engine_scan_totals(backend)))
+            log(f"[{name}] engine H/s over the requests (nonces scanned / "
+                f"device seconds): {hashes / device_s if device_s > 0 else 0.0:.4g}")
             if check is not None:
                 check(backend)
             server.assert_jax_free()

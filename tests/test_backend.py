@@ -141,6 +141,47 @@ def test_engine_restarts_after_idle():
     asyncio.run(run())
 
 
+def test_one_solve_opens_the_launch_cycle_spans_in_order(monkeypatch):
+    """The engine's profiler spans (obs.span → jax.profiler.TraceAnnotation)
+    partition one launch cycle: the engine packs and submits, the launch
+    thread dispatches, waits on the device and reads back, the engine
+    applies."""
+    import threading
+
+    import jax
+
+    opened, lock = [], threading.Lock()
+
+    class Recorder:
+        def __init__(self, name, **kwargs):
+            self.name = name
+
+        def __enter__(self):
+            with lock:
+                opened.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    async def run():
+        b = make_backend()
+        await b.setup()
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+        h = random_hash()
+        nc.validate_work(h, await b.generate(WorkRequest(h, EASY)), EASY)
+        await b.close()
+
+    asyncio.run(run())
+    cycle = ["dpow.engine.dispatch", "dpow.launch.dispatch", "dpow.launch.wait",
+             "dpow.launch.readback", "dpow.engine.apply"]
+    for name in cycle:
+        assert name in opened, (name, opened)
+    first = [opened.index(name) for name in cycle]
+    assert first == sorted(first), opened
+    assert all(name.startswith("dpow.") for name in opened), opened
+
+
 def test_waiter_timeout_does_not_spin_engine(backend):
     # Regression: a waiter abandoning via wait_for timeout left a job that
     # was neither done nor active, and the engine busy-spun on it.

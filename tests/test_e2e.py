@@ -541,9 +541,10 @@ def test_e2e_mqtt_worker_drop_gets_cancel_on_reconnect():
 
 
 def test_e2e_metrics_and_span_chain_for_one_request():
-    """Observability acceptance (ISSUE 1): one in-process HTTP request must
+    """Observability acceptance: one in-process HTTP request must
     (a) bump the ondemand request counter, (b) leave a complete span chain
-    accept → queue → publish → dispatch → pack → device → result → winner,
+    receive → accept → queue → publish → dispatch → submit → pack → device
+    → result → result_in → winner → resolve → reply,
     and (c) surface it all — request-latency histogram, per-stage spans,
     engine batch-occupancy and device-time — as valid Prometheus text on
     GET /metrics of the server upcheck port."""
@@ -559,8 +560,9 @@ def test_e2e_metrics_and_span_chain_for_one_request():
             "dpow_request_stage_seconds", labelnames=("stage",))
         stage_counts_before = {
             s: stage_hist.count_of(s)
-            for s in ("queue", "publish", "dispatch", "pack", "device",
-                      "result", "winner")
+            for s in ("accept", "queue", "publish", "dispatch", "submit",
+                      "pack", "device", "result", "result_in", "winner",
+                      "resolve", "cancel", "reply")
         }
         broker = Broker()
         runner, server, store, clients = await start_stack(broker, n_clients=1)
@@ -583,11 +585,16 @@ def test_e2e_metrics_and_span_chain_for_one_request():
                 tid = tracer.id_for(h)
                 assert tid is not None
                 stages = [s for s, _ in tracer.get(tid)]
-                for want in ("accept", "queue", "publish", "dispatch",
-                             "pack", "device", "result", "winner"):
+                for want in ("receive", "accept", "queue", "publish",
+                             "dispatch", "submit", "pack", "device", "result",
+                             "result_in", "winner", "resolve", "reply"):
                     assert want in stages, (want, stages)
+                assert stages[0] == "receive"
                 assert stages.index("accept") < stages.index("publish")
                 assert stages.index("publish") < stages.index("result")
+                assert stages.index("result") < stages.index("result_in")
+                assert stages.index("winner") < stages.index("resolve")
+                assert stages.index("resolve") < stages.index("reply")
                 # ... and each stage observed into the shared histogram
                 for s, before in stage_counts_before.items():
                     assert stage_hist.count_of(s) > before, s
@@ -631,6 +638,54 @@ def test_e2e_metrics_and_span_chain_for_one_request():
                 # machine-readable twin of the same surface
                 snap = obs.snapshot()
                 assert snap["dpow_server_requests_total"]["series"]["ondemand"] >= 1
+        finally:
+            await stop_stack(runner, clients)
+
+    run(main())
+
+
+def test_e2e_server_stage_chain_brackets_the_request_latency():
+    """The server's stage chain receive → reply breaks down the request's
+    dpow_server_request_seconds observation: no longer than it, and at
+    least 90% of it."""
+    from tpu_dpow import obs
+
+    async def main():
+        reg = obs.get_registry()
+        tracer = obs.get_tracer()
+        latency = reg.histogram(
+            "dpow_server_request_seconds", labelnames=("work_type",))
+
+        def observed():
+            series = latency.collect().get(("ondemand",))
+            return (series["sum"], series["count"]) if series else (0.0, 0)
+
+        broker = Broker()
+        runner, server, store, clients = await start_stack(broker, n_clients=1)
+        try:
+            async with aiohttp.ClientSession() as http:
+                url = f"http://127.0.0.1:{runner.ports['service']}/service/"
+                h = random_hash()
+                sum0, n0 = observed()
+                async with http.post(
+                    url, json={"user": "svc", "api_key": "secret", "hash": h}
+                ) as resp:
+                    body = await resp.json()
+                assert "work" in body, body
+                sum1, n1 = observed()
+                assert n1 == n0 + 1
+                request_s = sum1 - sum0
+                marks = dict(tracer.get(tracer.id_for(h)))
+                chain = marks["reply"] - marks["receive"]
+                # (1 us: the resolution of a difference of two wall stamps)
+                assert 0.9 * request_s <= chain <= request_s + 1e-6, (chain, request_s)
+                # ... and the server stages, each timed from its parent, sum
+                # to the same chain.
+                spans = dict(tracer.spans(tracer.id_for(h)))
+                server_chain = sum(spans[s] for s in (
+                    "accept", "queue", "publish", "result_in", "winner",
+                    "resolve", "reply"))
+                assert abs(server_chain - chain) < 1e-6
         finally:
             await stop_stack(runner, clients)
 

@@ -208,6 +208,84 @@ def test_tracer_span_chain_and_stage_histogram():
     assert h.count_of("queue") == 1 and h.count_of("publish") == 1
 
 
+def _stage_sum(reg, stage):
+    series = reg.histogram("dpow_request_stage_seconds", "", ("stage",)).collect()
+    return series[(stage,)]["sum"] if (stage,) in series else None
+
+
+def test_tracer_times_each_stage_from_its_parent_while_interleaved():
+    """cancel and reply run in different tasks after winner/resolve: each
+    is timed from its own parent, not from whichever mark came last."""
+    reg = Registry()
+    t = Tracer(registry=reg)
+    tid = t.begin("H" * 64, "receive", at=100.0)
+    for stage, at in (("accept", 100.5), ("queue", 101.0), ("publish", 102.0),
+                      ("result_in", 110.0), ("winner", 111.0),
+                      ("resolve", 113.0), ("cancel", 114.0), ("reply", 117.0)):
+        t.mark(tid, stage, at=at)
+    assert _stage_sum(reg, "result_in") == 8.0  # from publish
+    assert _stage_sum(reg, "cancel") == 3.0     # from winner, not resolve
+    assert _stage_sum(reg, "reply") == 4.0      # from resolve, not cancel
+    assert dict(t.spans(tid))["reply"] == 4.0
+    # The server chain sums to receive -> reply.
+    chain = ("accept", "queue", "publish", "result_in", "winner", "resolve", "reply")
+    assert sum(_stage_sum(reg, s) for s in chain) == 17.0
+
+
+def test_tracer_parentless_stage_is_not_observed():
+    """A worker in another process starts its trace empty at alias():
+    dispatch has no publish to be timed from, so nothing is observed,
+    while the worker's own chain from dispatch on is."""
+    reg = Registry()
+    t = Tracer(registry=reg)
+    tid = "0123456789abcdef"
+    t.alias("W" * 64, tid)
+    t.mark_hash("W" * 64, "dispatch", at=5.0)
+    t.mark_hash("W" * 64, "submit", at=5.25)
+    t.mark_hash("W" * 64, "pack", at=5.5)
+    assert _stage_sum(reg, "dispatch") is None
+    assert _stage_sum(reg, "submit") == 0.25
+    assert _stage_sum(reg, "pack") == 0.5
+    # A stage outside the table keeps the previous-mark rule.
+    t.mark(tid, "custom", at=6.0)
+    assert _stage_sum(reg, "custom") == 0.5
+
+
+def test_tracer_mark_at_stamps_the_given_instant():
+    reg = Registry()
+    t = Tracer(registry=reg)
+    tid = t.begin("A" * 64, "receive", at=t.now() - 2.0)
+    t.mark(tid, "accept")
+    assert 2.0 <= _stage_sum(reg, "accept") < 3.0
+    t.mark_hash("A" * 64, "queue", at=t.get(tid)[-1][1] + 0.125)
+    assert _stage_sum(reg, "queue") == 0.125
+    assert [s for s, _ in t.get(tid)] == ["receive", "accept", "queue"]
+
+
+def test_span_never_imports_jax():
+    """obs.span is a no-op in a process that never imported jax (the
+    server), and stays one: it must not pull jax in."""
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from tpu_dpow import obs\n"
+            "with obs.span('dpow.engine.wait'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'span imported jax'\n"
+            "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_span_is_a_trace_annotation_once_jax_is_imported():
+    import jax
+
+    with obs.span("dpow.engine.apply") as s:
+        assert isinstance(s, jax.profiler.TraceAnnotation)
+
+
 def test_tracer_unknown_ids_are_noops_and_store_is_bounded():
     from tpu_dpow.obs import trace as trace_mod
 

@@ -149,6 +149,7 @@ class WorkHandler:
             "dpow_client_queue_depth", "Work items waiting for a worker slot")
         self._m_ongoing = reg.gauge(
             "dpow_client_ongoing", "Work items currently in the engine")
+        self._tracer = obs.get_tracer()
 
     def _bump(self, event: str) -> None:
         self.stats[event] += 1
@@ -287,6 +288,7 @@ class WorkHandler:
             bh = request.block_hash
             job = _OngoingJob(request)
             self.ongoing[bh] = job
+            self._tracer.mark_hash(bh, "submit")
             try:
                 work = await self.backend.generate(request)
             except WorkCancelled:

@@ -8,9 +8,11 @@ telemetry surface:
   registry  — process-local Counter / Gauge / Histogram with label sets and
               fixed log2 latency buckets, safe from executor threads;
   trace     — span API stamping one WorkRequest through the whole pipeline
-              (accept → queue → publish → dispatch → pack → device →
-              result → winner/cancel), trace id riding the existing MQTT
-              payloads;
+              (receive → accept → queue → publish → dispatch → pack →
+              device → result → result_in → winner → resolve → reply),
+              each stage timed from the stage that caused it, trace id
+              riding the existing MQTT payloads; ``span`` puts engine
+              spans into the JAX profiler's trace;
   prom      — Prometheus text-format v0.0.4 renderer + parser and the
               aiohttp GET /metrics route (server upcheck port, client
               metrics port).
@@ -18,6 +20,7 @@ telemetry surface:
 Entry points:
   obs.get_registry()  — the process-wide Registry
   obs.get_tracer()    — the process-wide Tracer
+  obs.span(name)      — a profiler span where jax is imported, else a no-op
   obs.snapshot()      — machine-readable dump of every metric (what
                         bench.py and the harness scripts consume instead
                         of parsing logs)
@@ -36,7 +39,14 @@ from .registry import (  # noqa: F401
     get_registry,
 )
 from .ledger import LEDGER, LeakLedger, get_ledger  # noqa: F401
-from .trace import STAGES, Tracer, get_tracer, is_trace_id, new_trace_id  # noqa: F401
+from .trace import (  # noqa: F401
+    STAGES,
+    Tracer,
+    get_tracer,
+    is_trace_id,
+    new_trace_id,
+    span,
+)
 from .prom import add_metrics_route, histogram_quantile, parse_text, render  # noqa: F401
 
 

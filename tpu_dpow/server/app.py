@@ -1126,6 +1126,9 @@ class DpowServer:
             block_hash, work, client, trace_id = wire.decode_result_any(content)
         except ValueError:
             return
+        # Stamped before the store work below; marked `result_in` only if
+        # this result wins the election.
+        result_in = self._tracer.now()
 
         # Work still wanted? (hash deleted once its frontier moved on)
         available = await self.store.get(f"block:{block_hash}")
@@ -1213,6 +1216,7 @@ class DpowServer:
             # and a bogus/losing result carrying a forged id would hijack
             # the live request's trace before validation rejected it.
             self._tracer.alias(block_hash, trace_id)
+        self._tracer.mark_hash(block_hash, "result_in", at=result_in)
         self._tracer.mark_hash(block_hash, "winner")
         # Read BEFORE resolving the future: the moment set_result runs, any
         # await below can hand the loop to the last waiter's teardown,
@@ -1230,6 +1234,7 @@ class DpowServer:
         future = self.work_futures.get(block_hash)
         if future is not None and not future.done():
             future.set_result(work)
+            self._tracer.mark_hash(block_hash, "resolve")
         if self.replica is not None:
             # Forwarders (and, for adopted dispatches, the dead owner's
             # forwarders from its journal) get the answer on their
@@ -1426,10 +1431,13 @@ class DpowServer:
         (labeled by the work type actually served, or "unresolved" when the
         request died before the precache/on-demand decision)."""
         t0 = self.clock.time()
+        # The trace's `receive` stamp, taken at the same instant: the
+        # stage chain receive → reply then breaks this histogram down.
+        received = self._tracer.now()
         self._m_inflight.inc()
         served = {"work_type": "unresolved"}
         try:
-            return await self._service_request(data, served)
+            return await self._service_request(data, served, received)
         finally:
             self._m_inflight.dec()
             self._m_request_seconds.observe(
@@ -1440,7 +1448,9 @@ class DpowServer:
             # died unresolved is neither (it never reached the decision).
             self.precache.note_request(served["work_type"])
 
-    async def _service_request(self, data: dict, served: dict) -> dict:
+    async def _service_request(
+        self, data: dict, served: dict, received: float
+    ) -> dict:
         if self.draining:
             # Retire-after-drain (autoscale actuator contract): this
             # replica is leaving rotation — refuse new work with the
@@ -1476,7 +1486,8 @@ class DpowServer:
             # shedding if a dispatch is needed and the window is full;
             # hard mode raises Busy here (429 + Retry-After, api.py).
             over_quota = await self.admission.consume_quota(service)
-            self._tracer.begin(block_hash)  # stage: accept
+            trace_id = self._tracer.begin(block_hash, "receive", at=received)
+            self._tracer.mark(trace_id, "accept")
 
             work = await self.store.get(f"block:{block_hash}")
             if work is None:
@@ -1533,6 +1544,7 @@ class DpowServer:
                 )
                 raise RetryRequest()
 
+            self._tracer.mark(trace_id, "reply")
             logger.info("request handled for %s -> %s : %s", service, work_type, block_hash)
             return {"work": work, "hash": block_hash}
 
