@@ -286,6 +286,34 @@ def test_span_is_a_trace_annotation_once_jax_is_imported():
         assert isinstance(s, jax.profiler.TraceAnnotation)
 
 
+def test_engine_counts_jit_seconds_by_phase_once_per_process():
+    """JAX's compile events feed dpow_engine_jit_seconds_total{phase}; two
+    engines in one process still count each event once, and a jit traced
+    inside another's trace is not counted again."""
+    import jax
+
+    from tpu_dpow.backend.jax_backend import JIT_PHASES, JaxWorkBackend
+
+    JaxWorkBackend(kernel="xla", sublanes=8, iters=8)
+    JaxWorkBackend(kernel="xla", sublanes=8, iters=8)
+    seconds = obs.get_registry().counter(
+        "dpow_engine_jit_seconds_total", "", ("phase",))
+    assert sorted(JIT_PHASES.values()) == ["compile", "lower", "trace"]
+    before = {p: seconds.value(p) for p in JIT_PHASES.values()}
+    for i, event in enumerate(JIT_PHASES):
+        jax.monitoring.record_event_duration_secs(event, 0.25 * (i + 1), fun_name="f")
+    jax.monitoring.record_event_duration_secs("/jax/other_duration", 7.0)
+    got = {p: seconds.value(p) - before[p] for p in JIT_PHASES.values()}
+    assert got == pytest.approx({"trace": 0.25, "lower": 0.5, "compile": 0.75})
+
+    trace_event = next(e for e, p in JIT_PHASES.items() if p == "trace")
+    jax.monitoring.record_scalar(trace_event, 0.0, fun_name="outer")
+    jax.monitoring.record_scalar(trace_event, 0.0, fun_name="inner")
+    jax.monitoring.record_event_duration_secs(trace_event, 1.0, fun_name="inner")
+    jax.monitoring.record_event_duration_secs(trace_event, 3.0, fun_name="outer")
+    assert seconds.value("trace") - before["trace"] == pytest.approx(0.25 + 3.0)
+
+
 def test_tracer_unknown_ids_are_noops_and_store_is_bounded():
     from tpu_dpow.obs import trace as trace_mod
 

@@ -91,6 +91,57 @@ def test_pallas_interpret_batch():
         assert got[i] == want
 
 
+def _count_primitive(jaxpr, name: str) -> int:
+    """Equations of primitive ``name`` in a jaxpr and, recursively, in the
+    sub-jaxprs its equations carry (pallas_call, cond, scan, while, jit)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count_primitive(sub, name)
+    return n
+
+
+def test_pallas_kernel_traces_the_tile_body_once_per_group():
+    """At the engine's geometry (group 8, the flat 12-round body) the kernel
+    holds one traced tile, not one per tile of a group: Mosaic's lowering
+    unrolls the group loop, so the body need not be traced ``group`` times."""
+    import jax
+
+    from tpu_dpow.ops import blake2b
+
+    sublanes = 32
+    kernel = pallas_kernel.pallas_search_chunk_batch.trace(
+        jax.ShapeDtypeStruct((1, search.PARAMS_LEN), jnp.uint32),
+        sublanes=sublanes, iters=1024, nblocks=8, group=8, unroll=True,
+    ).jaxpr
+    lanes = jax.ShapeDtypeStruct((sublanes, 128), jnp.uint32)
+    word = jax.ShapeDtypeStruct((), jnp.uint32)
+    tile = jax.make_jaxpr(
+        lambda lo, hi, msg, d: blake2b.pow_meets_difficulty((lo, hi), msg, d, unroll=True)
+    )(lanes, lanes, [word] * 8, (word, word))
+    per_tile = _count_primitive(tile.jaxpr, "shift_left")
+    assert per_tile > 0
+    assert per_tile <= _count_primitive(kernel.jaxpr, "shift_left") < 2 * per_tile
+
+
+@pytest.mark.parametrize("group", [4, 8])
+def test_pallas_interpret_group_keeps_the_lowest_hit(group):
+    """Several hits inside one group of tiles: the launch still returns the
+    lowest offset of the window, as a one-tile-at-a-time scan would."""
+    h = RNG.bytes(32)
+    easy = 0xFF00000000000000  # ~1 in 256 nonces: every 1024-lane tile hits
+    params = jnp.asarray(search.pack_params(h, easy, base=4242))
+    n = pallas_kernel.chunk_size(8, 8)
+    want = int(search.search_chunk(params, chunk_size=n))
+    got = int(pallas_kernel.pallas_search_chunk(
+        params, sublanes=8, iters=8, group=group, interpret=True))
+    assert got == want == first_valid_offset(h, easy, 4242, want + 1)
+
+
 def test_pallas_launch_window_cap():
     h = RNG.bytes(32)
     params = jnp.asarray(search.pack_params(h, EASY, base=0))

@@ -118,6 +118,52 @@ SPEC_WASTE_ROWS = 2.0
 IDLE_EXIT_S = 5
 IDLE_SPAN_S = 1
 
+# The jax.monitoring duration events of one jit specialization's way to an
+# executable, by the phase label of dpow_engine_jit_seconds_total.
+JIT_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_jit_listener_lock = threading.Lock()
+_jit_listener_registered = False
+
+
+def _register_jit_listener() -> None:
+    """Count this process's host seconds in each jit phase, once per
+    process however many engines it builds: jax.monitoring keeps every
+    listener it is given, so a second one would count each event twice.
+
+    A jit traced inside another's trace (a jitted jnp function in a kernel
+    body) reports its own duration, already inside the outer one's; only
+    the outermost event of a phase on a thread is counted. JAX marks each
+    event's start with a scalar of the same name."""
+    global _jit_listener_registered
+    with _jit_listener_lock:
+        if _jit_listener_registered:
+            return
+        seconds = obs.get_registry().counter(
+            "dpow_engine_jit_seconds_total",
+            "Host seconds spent making jit executables, by phase", ("phase",))
+        open_events = threading.local()
+
+        def started(event: str, _value: float, **_kw) -> None:
+            if event in JIT_PHASES:
+                setattr(open_events, event, getattr(open_events, event, 0) + 1)
+
+        def ended(event: str, duration: float, **_kw) -> None:
+            phase = JIT_PHASES.get(event)
+            if phase is None:
+                return
+            depth = max(getattr(open_events, event, 0) - 1, 0)
+            setattr(open_events, event, depth)
+            if depth == 0:
+                seconds.inc(duration, phase)
+
+        jax.monitoring.register_scalar_listener(started)
+        jax.monitoring.register_event_duration_secs_listener(ended)
+        _jit_listener_registered = True
+
 
 @dataclass
 class _Job:
@@ -498,6 +544,7 @@ class JaxWorkBackend(WorkBackend):
         # vs device time (from the launch stamps), nonces scanned.
         reg = obs.get_registry()
         self._tracer = obs.get_tracer()
+        _register_jit_listener()
         self._m_hashes = reg.counter(
             "dpow_engine_hashes_total", "Nonces scanned on device", ("engine",))
         self._m_solutions = reg.counter(

@@ -90,10 +90,13 @@ def _search_core(
 
     def scan_block(k, best):
         def compute(_):
-            group_best = tile_best(k * group)
-            for j in range(1, group):
-                group_best = jnp.minimum(group_best, tile_best(k * group + j))
-            return group_best
+            # Fully unrolled: the tile body is traced once, and Mosaic's
+            # lowering still emits ``group`` straight-line copies of it.
+            return lax.fori_loop(
+                0, group,
+                lambda j, acc: jnp.minimum(acc, tile_best(k * group + j)),
+                _NOT_FOUND_I32, unroll=True,
+            )
 
         # Early exit: after a hit, every remaining group is a no-op.
         return lax.cond(best == _NOT_FOUND_I32, compute, lambda _: best, None)
