@@ -198,7 +198,8 @@ async def start_worker(server: ServerChild, name: str, extra: list):
         f"max_batch={backend.max_batch} pipeline={backend.pipeline} "
         f"run_mode={backend.run_mode} warm_shapes={backend.warm_shapes}")
     log(f"[{name}] compile (setup self-test) {t_setup:.2f} s; warm ladder "
-        f"{t_warm:.2f} s; warmed shapes (batch, steps): {sorted(backend._warm)}")
+        f"{t_warm:.2f} s; warmed programs (batch, steps; None = any run length): "
+        f"{sorted(backend._warm)}")
     return client, backend
 
 

@@ -1,6 +1,7 @@
 """JaxWorkBackend: generate/cancel/dedup/batch semantics on the CPU path."""
 
 import asyncio
+import math
 
 import numpy as np
 import pytest
@@ -8,6 +9,7 @@ import pytest
 from tpu_dpow.backend import WorkCancelled, WorkError, get_backend
 from tpu_dpow.backend.jax_backend import JaxWorkBackend
 from tpu_dpow.models import WorkRequest, WorkType
+from tpu_dpow.ops import pallas_kernel
 from tpu_dpow.utils import nanocrypto as nc
 
 
@@ -525,7 +527,90 @@ def test_persistent_warm_engine_never_compiles_on_the_dispatch_path():
     asyncio.run(asyncio.wait_for(run(), 120))
 
 
-def test_warm_engine_never_compiles_on_the_dispatch_path():
+def make_pallas_backend(**kw):
+    """The plain chunked Pallas path (interpreted) at a tiny geometry: a
+    window is 8*128*2 nonces, a launch 2 windows per step."""
+    return JaxWorkBackend(
+        kernel="pallas", interpret=True, sublanes=8, iters=2, nblocks=2,
+        group=1, **kw,
+    )
+
+
+def _difficulty_for_steps(b, steps):
+    """A difficulty _steps_for maps to run length ``steps``: its median
+    solve is 0.4 ``steps`` launch windows (the rung holds up to 0.5)."""
+    p = math.log(2) / (b.chunk * steps * 0.4)
+    return int((1 - p) * 2**64)
+
+
+def test_pallas_ladder_holds_one_program_per_batch():
+    """On the plain chunked Pallas path the window count is a runtime
+    operand: the warm ladder is one program per batch size, cold until
+    batch 1 (setup) and max_batch (the warm task) are compiled, and any
+    run length runs on a warm batch's program."""
+
+    async def run():
+        b = make_pallas_backend(warm_shapes=True, max_batch=4, run_steps=16)
+        assert b._step_counts() == [1, 4, 16]
+        assert b.cold_shapes() == [(1, None), (4, None)]
+        await b.setup()
+        await b._warm_task
+        assert b.cold_shapes() == []
+        assert b._warm == {(1, None), (4, None)}
+        for steps in (1, 4, 16):
+            assert b._steps_for(_difficulty_for_steps(b, steps)) == steps
+            assert b._pick_shape(1, steps) == (1, steps)
+            assert b._pick_shape(3, steps) == (4, steps)
+        await b.close()
+
+    asyncio.run(asyncio.wait_for(run(), 120))
+
+
+def test_engine_launch_programs_gauge_counts_the_warm_set():
+    """dpow_engine_launch_programs reads the programs the engine holds:
+    2 on the plain Pallas path, one per (batch, steps) on a static grid."""
+
+    async def run():
+        b = make_pallas_backend(warm_shapes=True, max_batch=4, run_steps=16)
+        await b.setup()
+        await b._warm_task
+        assert b._m_programs.value() == 2
+        await b.close()
+        b = make_backend(warm_shapes=True, max_batch=4, run_steps=16)
+        await b.setup()
+        await b._warm_task
+        assert len(b._warm) == 6 and b._m_programs.value() == 6
+        await b.close()
+
+    asyncio.run(asyncio.wait_for(run(), 120))
+
+
+def test_engine_launches_counter_by_batch_and_steps():
+    """dpow_engine_launches_total counts each launch under its padded batch
+    and run length: one batch-1 program serves steps 1, 4 and 16."""
+
+    async def run():
+        b = make_pallas_backend(warm_shapes=True, max_batch=4, run_steps=16)
+        before = {s: b._m_launches.value("1", str(s)) for s in (1, 4, 16)}
+        warm_before = b._m_launches.value("4", "1")
+        await b.setup()
+        await b._warm_task
+        assert b._m_launches.value("1", "1") == before[1] + 1  # self-test
+        assert b._m_launches.value("4", "1") == warm_before + 1  # warm task
+        for steps in (4, 16):
+            h = random_hash()
+            d = _difficulty_for_steps(b, steps)
+            nc.validate_work(h, await b.generate(WorkRequest(h, d)), d)
+        for steps in (4, 16):
+            assert b._m_launches.value("1", str(steps)) > before[steps]
+        assert b._m_programs.value() == 2
+        await b.close()
+
+    asyncio.run(asyncio.wait_for(run(), 120))
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_warm_engine_never_compiles_on_the_dispatch_path(kernel):
     """Regression guard for the e2e soak flake: a COLD warm_shapes engine
     hit by a burst must serve every request from shapes already in the
     warm set at dispatch time — never launch an unwarmed shape inline.
@@ -533,18 +618,26 @@ def test_warm_engine_never_compiles_on_the_dispatch_path():
     host; parked on the dispatch path it stalls every in-flight request
     past the server's 5 s default service timeout, which is exactly how
     test_e2e_soak_with_cancels_and_timeouts used to time out whenever
-    earlier tests perturbed arrival timing into an uncached shape."""
+    earlier tests perturbed arrival timing into an uncached shape.
+
+    On the plain Pallas path, once warm, launches at run lengths 1, 4 and
+    16 trace nothing: the kernel's jit cache does not grow."""
 
     async def run():
-        b = make_backend(warm_shapes=True, max_batch=16)
+        if kernel == "xla":
+            b = make_backend(warm_shapes=True, max_batch=16)
+        else:
+            b = make_pallas_backend(warm_shapes=True, max_batch=16, run_steps=16)
         await b.setup()
         real_dispatch = b._dispatch_next
-        cold_dispatches = []
+        cold_dispatches, shapes = [], set()
 
         def recording_dispatch(*args, **kw):
             rec = real_dispatch(*args, **kw)
-            if rec is not None and rec.shape not in b._warm:
-                cold_dispatches.append(rec.shape)
+            if rec is not None:
+                shapes.add(rec.shape)
+                if b._program(*rec.shape) not in b._warm:
+                    cold_dispatches.append(rec.shape)
             return rec
 
         b._dispatch_next = recording_dispatch
@@ -555,9 +648,21 @@ def test_warm_engine_never_compiles_on_the_dispatch_path():
         assert not cold_dispatches, (
             f"dispatch path launched unwarmed shapes {cold_dispatches}"
         )
+        if kernel == "pallas":
+            await b._warm_task
+            compiled = pallas_kernel.pallas_search_chunk_batch._cache_size()
+            for steps in (1, 4, 16):
+                d = _difficulty_for_steps(b, steps)
+                reqs = [WorkRequest(random_hash(), d) for _ in range(2)]
+                works = await asyncio.gather(*(b.generate(r) for r in reqs))
+                for r, w in zip(reqs, works):
+                    nc.validate_work(r.block_hash, w, d)
+            assert {s for _, s in shapes} >= {1, 4, 16}
+            assert not cold_dispatches
+            assert pallas_kernel.pallas_search_chunk_batch._cache_size() == compiled
         await b.close()
 
-    asyncio.run(run())
+    asyncio.run(asyncio.wait_for(run(), 120))
 
 
 # -- launch timeout (hang protection) -------------------------------------

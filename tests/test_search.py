@@ -184,6 +184,62 @@ def test_pallas_interpret_multiblock_sentinel_when_dry():
     assert got[0] == search.SENTINEL
 
 
+def _late_hit_row(difficulty: int, lo: int, hi: int) -> tuple:
+    """A hash and its row (base 0) whose first hit lies in [lo, hi)."""
+    for i in range(200):
+        h = hashlib.blake2b(b"late-hit %d" % i, digest_size=32).digest()
+        params = search.pack_params(h, difficulty, base=0)
+        off = int(search.search_chunk(jnp.asarray(params), chunk_size=hi))
+        if lo <= off < hi:
+            return h, params
+    raise AssertionError("no hash with a late first hit")
+
+
+def test_pallas_runtime_window_count_matches_the_static_grid():
+    """One program, its window count a runtime grid bound, called with 1x,
+    4x and 16x nblocks windows: row by row the same offsets as the static
+    grid of that many windows — a hit only in a late window, a dry row
+    (SENTINEL) and difficulty-0 pad rows — and one trace for all three."""
+    sub, it, nb = 8, 2, 2
+    window = pallas_kernel.chunk_size(sub, it)
+    counts = (nb, 4 * nb, 16 * nb)
+    hard = 0xFFFF800000000000  # ~1 in 32768 nonces
+    h, late = _late_hit_row(hard, counts[1] * window, counts[2] * window)
+    pad = search.pack_params(bytes(32), 0, base=0)
+    dry = search.pack_params(bytes(32), (1 << 64) - 1, base=0)
+    easy = search.pack_params(RNG.bytes(32), EASY, base=99)
+    params = jnp.asarray(np.stack([late, dry, pad, easy, pad]))
+    geometry = dict(sublanes=sub, iters=it, interpret=True)
+    bound = counts[-1]
+    fn = pallas_kernel.pallas_search_chunk_batch
+    got, traced = {}, []
+    for n in counts:
+        got[n] = np.asarray(fn(params, pallas_kernel.window_count(n, bound),
+                               nblocks=bound, **geometry))
+        traced.append(fn._cache_size())
+    assert traced[0] == traced[1] == traced[2], "a window count traced again"
+    for n in counts:
+        want = np.asarray(fn(params, nblocks=n, **geometry))
+        np.testing.assert_array_equal(got[n], want)
+    sentinel = int(search.SENTINEL)
+    assert [int(got[n][0]) == sentinel for n in counts] == [True, True, False]
+    assert all(int(got[n][1]) == sentinel for n in counts)
+    assert all(int(got[n][2]) == int(got[n][4]) == 0 for n in counts)
+    off = int(got[counts[2]][0])
+    assert off >= counts[1] * window
+    assert first_valid_offset(h, hard, 0, off + 1) == off
+
+
+@pytest.mark.parametrize("windows", [0, 33, -1])
+def test_pallas_window_count_outside_the_bound_is_refused(windows):
+    """A count past the program's static bound (offsets past 2^31) or
+    below 1 (a grid that never writes the result) fails on the host."""
+    with pytest.raises(ValueError, match="outside"):
+        pallas_kernel.window_count(windows, 32)
+    count = pallas_kernel.window_count(32, 32)
+    assert count.dtype == np.int32 and int(count) == 32
+
+
 # -- device-resident run loop (ops/runloop.py) ---------------------------
 
 

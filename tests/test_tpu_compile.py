@@ -60,9 +60,18 @@ def _params(batch, sharding):
 
 
 @pytest.mark.parametrize("batch", [1, 16])
-def test_pallas_chunk_batch_compiles(one_chip, batch):
+@pytest.mark.parametrize("windows", ["static", "runtime"])
+def test_pallas_chunk_batch_compiles(one_chip, batch, windows):
+    """``static``: the grid the fan, mesh and run loop compile. ``runtime``:
+    the engine's plain path, its window count an int32 operand under the
+    static bound of run_steps (16) launches of nblocks windows."""
+    geometry = dict(GEOMETRY)
+    args = [_params(batch, one_chip)]
+    if windows == "runtime":
+        geometry["nblocks"] *= 16
+        args.append(jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
     compiled = pallas_kernel.pallas_search_chunk_batch.lower(
-        _params(batch, one_chip), unroll=True, **GEOMETRY
+        *args, unroll=True, **geometry
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 

@@ -93,6 +93,47 @@ def test_pallas_multiblock_early_exit_on_device(tpu_device):
     assert _plant(h, base + got) >= diff
 
 
+def test_pallas_runtime_window_count_on_device(tpu_device):
+    """The engine's plain-path launch: one program whose window count is a
+    runtime grid bound returns, at 1x, 4x and 16x nblocks windows, row by
+    row what the static grid of that many windows returns — a row planted
+    in the last window, a dry row, difficulty-0 pads — traced once."""
+    import jax.numpy as jnp
+
+    from tpu_dpow.ops import pallas_kernel, search
+
+    sub, it, nb = 8, 8, 2
+    window = sub * 128 * it
+    counts = (nb, 4 * nb, 16 * nb)
+    h = secrets.token_bytes(32)
+    base = 5 << 30
+    offset = (counts[2] - 1) * window + 123  # the last window
+    diff = _plant(h, base + offset)
+    rows = [
+        search.pack_params(h, diff, base),
+        search.pack_params(h, (1 << 64) - 1, base),
+        search.pack_params(bytes(32), 0, 0),
+        search.pack_params(bytes(32), 0, 0),
+    ]
+    params = jnp.asarray(np.stack(rows))
+    geometry = dict(sublanes=sub, iters=it, group=4)
+    fn = pallas_kernel.pallas_search_chunk_batch
+    got, traced = {}, []
+    for n in counts:
+        got[n] = np.asarray(fn(
+            params, pallas_kernel.window_count(n, counts[-1]),
+            nblocks=counts[-1], **geometry))
+        traced.append(fn._cache_size())
+    assert traced[0] == traced[1] == traced[2]
+    for n in counts:
+        want = np.asarray(fn(params, nblocks=n, **geometry))
+        np.testing.assert_array_equal(got[n], want)
+        assert int(got[n][1]) == search.SENTINEL
+        assert int(got[n][2]) == int(got[n][3]) == 0
+    hit = int(got[counts[2]][0])
+    assert hit <= offset and _plant(h, base + hit) >= diff
+
+
 def test_flagship_geometry_finds_and_validates(tpu_device):
     """The bench geometry (32x128x1024, nblocks, group 8) end-to-end at an
     easy difficulty: solution found and hashlib-valid."""
@@ -255,7 +296,8 @@ def test_backend_run_mode_and_warm_shapes_on_device():
         works = await asyncio.gather(*(b.generate(r) for r in reqs))
         for r, w in zip(reqs, works):
             nc.validate_work(r.block_hash, w, easy)
-        assert (4, 1) in b._warm
+        # The plain Pallas path: one program per batch, every run length.
+        assert b._warm == {(1, None), (4, None)} and not b.cold_shapes()
         await b.close()
 
     asyncio.run(run())

@@ -445,6 +445,16 @@ class JaxWorkBackend(WorkBackend):
                 "(the pmap fan) for persistent multi-chip search"
             )
         self.run_mode = run_mode
+        # On the plain chunked Pallas path the window count is a runtime
+        # operand of the kernel (pallas_kernel.window_count), so one compiled
+        # program per batch size serves every run length. The fan, mesh,
+        # persistent and XLA paths compile one program per (batch, steps).
+        self._runtime_windows = (
+            self.kernel == "pallas"
+            and self.fan is None
+            and self.mesh is None
+            and run_mode == "chunked"
+        )
         if control_poll_steps < 0:
             raise WorkError("control_poll_steps must be >= 0 (0 = auto)")
         # Poll cadence tradeoff: each poll is an io_callback (a host touch)
@@ -462,12 +472,11 @@ class JaxWorkBackend(WorkBackend):
         self.persistent_steps = max(persistent_steps, 1)
         self.max_batch = max_batch
         self.interpret = interpret
-        # Every distinct (batch, steps) launch shape is a separate XLA
-        # compile (~20 s each for the Pallas kernel at the default
-        # geometry on a v5e host; the persistent compile cache saves
-        # restarts). With shape
+        # Every launch program (see _program) is a separate XLA compile
+        # (seconds each for the Pallas kernel at the default geometry on a
+        # v5e host; the persistent compile cache saves restarts). With shape
         # warming on — the TPU default — the engine only ever launches
-        # shapes from _warm, and a background task grows that set after
+        # programs from _warm, and a background task grows that set after
         # setup, so no request stalls behind a compile wall. Off (the CPU
         # default, where compiles are cheap), everything counts as warm.
         self.warm_shapes = on_tpu if warm_shapes is None else warm_shapes
@@ -571,6 +580,13 @@ class JaxWorkBackend(WorkBackend):
             ("engine",))
         self._m_rungs = reg.gauge(
             "dpow_engine_rungs", "Distinct difficulty rungs with live demand")
+        self._m_launches = reg.counter(
+            "dpow_engine_launches_total",
+            "Device launches submitted, by padded batch and run length "
+            "(windows of nblocks)", ("batch", "steps"))
+        self._m_programs = reg.gauge(
+            "dpow_engine_launch_programs",
+            "Compiled launch programs the engine holds (its warm set)")
         # Per-device families (fan mode; docs/observability.md catalogue).
         # Label cardinality is the local device count (<= 8 on every target
         # topology), never unbounded.
@@ -686,7 +702,7 @@ class JaxWorkBackend(WorkBackend):
                 f"backend self-test failed "
                 f"(nonce {int(hi.flat[0]):08x}{int(lo.flat[0]):08x})"
             )
-        self._warm.add((1, 1))
+        self._warm.add(self._program(1, 1))
         if not self.warm_shapes and len(self._step_counts()) > 1:
             # Warming off (CPU: compiles are cheap): pay the run-mode
             # ladder compiles inline so behavior is fully deterministic.
@@ -694,18 +710,21 @@ class JaxWorkBackend(WorkBackend):
             # rung exists even at run_steps=1 and the first request must
             # not eat its compile.)
             for steps in self._step_counts()[1:]:
+                if self._program(1, steps) == self._program(1, 1):
+                    continue  # the self-test's program runs this length
                 await self._timed_launch(np.stack([probe]), steps)
-                self._warm.add((1, steps))
+                self._warm.add(self._program(1, steps))
+        self._m_programs.set(len(self._warm))
         if self.warm_shapes and self._warm_task is None and (
             self.max_batch > 1 or self.run_steps > 1
         ):
             # With warming ON (TPU), setup() returns after the single
-            # self-test compile; the rest of the shape ladder — including
-            # the (1, steps) run-mode rungs — compiles in the background.
-            # Those are ~20 s EACH, and a client blocked in setup() serves
-            # nothing; a request arriving before its rung is warm just runs
-            # at the largest warmed step count (more round trips, still
-            # correct — see _pick_shape).
+            # self-test compile; the rest of the ladder — max_batch, and on
+            # the static-grid paths the (1, steps) run-mode rungs — compiles
+            # in the background. Each costs seconds, and a client blocked in
+            # setup() serves nothing; a request arriving before its rung is
+            # warm just runs at the largest warmed step count (more round
+            # trips, still correct — see _pick_shape).
             self._warm_task = asyncio.ensure_future(self._warmup_loop())
 
     async def generate(self, request: WorkRequest) -> str:
@@ -1354,8 +1373,8 @@ class JaxWorkBackend(WorkBackend):
         With shape warming on (TPU) there are exactly TWO: singleton and
         max_batch. Difficulty-0 padding rows are free on the Pallas path
         (measured: an all-pads batch-16 launch costs the bare round-trip
-        floor), so intermediate sizes would only multiply the compile
-        count — each extra shape is ~20 s of warmup during which the engine
+        floor), so intermediate sizes would only multiply the programs
+        (_program) — each one is seconds of warmup during which the engine
         would fall back to singleton launches and batching throughput would
         sit at 1/launch-time.
 
@@ -1372,7 +1391,7 @@ class JaxWorkBackend(WorkBackend):
         return sizes
 
     async def _warmup_loop(self) -> None:
-        """Background-compile the remaining (batch, steps) launch shapes.
+        """Background-compile the remaining launch programs (_program).
 
         Probe rows solve at offset 0, so device time is negligible — each
         iteration's cost is the compile itself, after which the shape
@@ -1394,11 +1413,11 @@ class JaxWorkBackend(WorkBackend):
             for b, steps in shapes:
                 if self._closed:
                     return
-                if (b, steps) in self._warm:
+                if self._program(b, steps) in self._warm:
                     continue
                 await self._timed_launch(np.stack([probe] * b), steps)
                 # dpowlint: disable=DPOW801 — one warm task exists per backend (close() joins it before a successor could start) and set.add is idempotent; a racing inline warm costs one duplicate compile, never corrupts state
-                self._warm.add((b, steps))
+                self._mark_warm(b, steps)
         except asyncio.CancelledError:
             raise
         except Exception:
@@ -1413,9 +1432,23 @@ class JaxWorkBackend(WorkBackend):
                 exc_info=True,
             )
 
+    def _program(self, b: int, steps: int) -> tuple:
+        """The compiled launch program a (batch, steps) launch runs, as the
+        warm set keys it: (b, steps), or (b, None) — one program for every
+        run length — where the window count is a runtime operand."""
+        return (b, None) if self._runtime_windows else (b, steps)
+
+    def _mark_warm(self, b: int, steps: int) -> None:
+        self._warm.add(self._program(b, steps))
+        self._m_programs.set(len(self._warm))
+
     def cold_shapes(self) -> list:
-        """The warm ladder's (batch, steps) launch shapes not compiled yet."""
-        ladder = {(b, s) for b in self._batch_sizes() for s in self._step_counts()}
+        """The warm ladder's launch programs (_program) not compiled yet."""
+        ladder = {
+            self._program(b, s)
+            for b in self._batch_sizes()
+            for s in self._step_counts()
+        }
         return sorted(ladder - self._warm)
 
     def _pick_shape(self, njobs: int, steps_want: int) -> tuple:
@@ -1434,6 +1467,8 @@ class JaxWorkBackend(WorkBackend):
         warmed_bs = sorted({b for b, _ in self._warm})
         fitting = [b for b in warmed_bs if b >= b_want]
         b = fitting[0] if fitting else warmed_bs[-1]
+        if self._runtime_windows:
+            return b, steps_want  # the batch's one program runs any length
         cands = [s for bb, s in self._warm if bb == b and s <= steps_want]
         steps = max(cands) if cands else steps_want  # compile inline if cold
         return b, steps
@@ -1441,14 +1476,17 @@ class JaxWorkBackend(WorkBackend):
     def _step_counts(self) -> list:
         """The quantized run lengths the engine may emit (ascending).
 
-        Each distinct count is a separate compile of the run loop, so the
-        default ladder is powers of four — few enough to warm at setup,
-        granular enough that easy difficulties return to the host (and thus
-        to fresh arrivals and cancels) after one or two windows. The
+        On the fan, mesh, persistent and XLA paths each distinct count is a
+        separate compile (_program), so the default ladder is powers of
+        four — few enough to warm at setup, granular enough that easy
+        difficulties return to the host (and thus to fresh arrivals and
+        cancels) after one or two windows. On the plain chunked Pallas path
+        the count is a runtime operand and costs no compile. The
         ``step_ladder="x2"`` option halves the quantization step (base
         difficulty then launches 2 windows instead of 4 — less span to
-        drain past the hit) at the cost of ~2x the warm compiles; which
-        wins is an on-chip measurement (benchmarks/latency.py A/B).
+        drain past the hit), at the cost of ~2x the warm compiles where
+        counts compile; which wins is an on-chip measurement
+        (benchmarks/latency.py A/B).
         """
         if self.run_mode == "persistent":
             # One steerable mega-shape (plus the singleton the setup probe
@@ -1504,6 +1542,7 @@ class JaxWorkBackend(WorkBackend):
         block registered: the launch reads dead zeros and just runs).
         ``devices`` pins the launch to a fan subset (degraded width /
         re-admission probes); None = the engine's full complement."""
+        self._m_launches.inc(1, str(params_batch.shape[-2]), str(steps))
         if self._executor is None:
             import concurrent.futures
 
@@ -1662,11 +1701,15 @@ class JaxWorkBackend(WorkBackend):
         with obs.span("dpow.launch.dispatch"):
             pj = jax.device_put(params_batch, self.device)
             if self.kernel == "pallas":
+                # One program per batch (_runtime_windows): the window count
+                # is a runtime operand under the static bound of run_steps.
+                bound = self.nblocks * self.run_steps
                 out = pallas_kernel.pallas_search_chunk_batch(
                     pj,
+                    pallas_kernel.window_count(nblocks, bound),
                     sublanes=self.sublanes,
                     iters=self.iters,
-                    nblocks=nblocks,
+                    nblocks=bound,
                     group=self.group,
                     interpret=self.interpret,
                 )
@@ -2105,7 +2148,7 @@ class JaxWorkBackend(WorkBackend):
         return rec
 
     def _apply_results(self, rec: "_Launch", lo_arr, hi_arr) -> None:
-        self._warm.add(rec.shape)  # organic warming
+        self._mark_warm(*rec.shape)  # organic warming
         timing = rec.timing
         if timing is not None:
             timing["t_apply"] = time.perf_counter()

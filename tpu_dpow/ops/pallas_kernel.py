@@ -200,6 +200,7 @@ def pallas_search_chunk(
 )
 def pallas_search_chunk_batch(
     params_batch: jnp.ndarray,
+    windows: jnp.ndarray | None = None,
     *,
     sublanes: int = DEFAULT_SUBLANES,
     iters: int = DEFAULT_ITERS,
@@ -219,6 +220,12 @@ def pallas_search_chunk_batch(
     the one dispatch with per-request early exit between windows — the
     persistent-kernel mode that amortizes dispatch overhead. The
     total per-request window is ``nblocks * sublanes * 128 * iters`` nonces.
+
+    ``windows`` (an int32 scalar, from ``window_count``) makes the window
+    count a runtime grid bound instead: the launch scans ``windows``
+    windows, and ``nblocks`` is only the static upper bound the 2^31-offset
+    guard holds. One compiled program per batch size then serves every run
+    length up to ``nblocks``.
     """
     if unroll is None:
         unroll = _default_unroll(interpret)
@@ -227,7 +234,7 @@ def pallas_search_chunk_batch(
     if nblocks * sublanes * 128 * iters >= 1 << 31:
         raise ValueError("total launch window must stay below 2^31 nonces")
     b = params_batch.shape[0]
-    if nblocks == 1:
+    if windows is None and nblocks == 1:
         kernel = functools.partial(
             _kernel_batched, sublanes=sublanes, iters=iters, unroll=unroll,
             group=group,
@@ -238,7 +245,7 @@ def pallas_search_chunk_batch(
             _kernel_blocks, sublanes=sublanes, iters=iters, unroll=unroll,
             group=group,
         )
-        grid = (b, nblocks)
+        grid = (b, nblocks if windows is None else windows)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.uint32),
@@ -247,6 +254,20 @@ def pallas_search_chunk_batch(
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         interpret=interpret,
     )(params_batch)[:, 0]
+
+
+def window_count(windows: int, nblocks: int) -> np.int32:
+    """A launch's runtime window count for ``pallas_search_chunk_batch``,
+    checked on the host against the program's static bound ``nblocks``.
+
+    Inside the program the count is traced, so nothing there can refuse a
+    count past the bound (whose offsets would cross 2^31) or below 1 (whose
+    grid never writes the result). Always an ``np.int32``: a weak-typed
+    Python int would trace a second program.
+    """
+    if not 1 <= windows <= nblocks:
+        raise ValueError(f"window count {windows} outside [1, {nblocks}]")
+    return np.int32(windows)
 
 
 def chunk_size(sublanes: int = DEFAULT_SUBLANES, iters: int = DEFAULT_ITERS) -> int:
