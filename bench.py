@@ -75,7 +75,8 @@ def measure(reps: int = 8) -> dict:
 
     def launch(p):
         return pallas_kernel.pallas_search_chunk_batch(
-            p, sublanes=sublanes, iters=iters, nblocks=nblocks, group=group
+            p, pallas_kernel.window_count(nblocks, nblocks),
+            sublanes=sublanes, iters=iters, nblocks=nblocks, group=group,
         )
 
     pj = jax.device_put(params, dev)

@@ -66,6 +66,7 @@ def run(reps: int) -> None:
     )
     kernel = "pallas" if on_tpu else "xla"
     chunk = sublanes * 128 * iters * nblocks
+    windows = pallas_kernel.window_count(nblocks, nblocks)
     rows = np.stack([search.pack_params(bytes(32), (1 << 64) - 1, 0)])
 
     # plain path: single-device kernel launch
@@ -74,7 +75,8 @@ def run(reps: int) -> None:
     def plain():
         if kernel == "pallas":
             return pallas_kernel.pallas_search_chunk_batch(
-                pj, sublanes=sublanes, iters=iters, nblocks=nblocks, group=group
+                pj, windows, sublanes=sublanes, iters=iters, nblocks=nblocks,
+                group=group,
             )
         return search.search_chunk_batch(pj, chunk_size=chunk)
 
@@ -84,7 +86,7 @@ def run(reps: int) -> None:
 
     def ganged():
         return sharded_search_chunk_batch(
-            params, mesh=mesh, chunk_per_shard=chunk, kernel=kernel,
+            params, windows, mesh=mesh, chunk_per_shard=chunk, kernel=kernel,
             sublanes=sublanes, iters=iters, nblocks=nblocks, group=group,
         )
 

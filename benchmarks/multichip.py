@@ -36,7 +36,7 @@ import numpy as np
 def run(n_devices: int, batch_shards: int, chunk_per_shard: int, reps: int) -> None:
     import jax
 
-    from tpu_dpow.ops import search
+    from tpu_dpow.ops import pallas_kernel, search
     from tpu_dpow.parallel import (
         make_mesh,
         replicate_params,
@@ -66,13 +66,16 @@ def run(n_devices: int, batch_shards: int, chunk_per_shard: int, reps: int) -> N
     params = replicate_params(rows, mesh)
 
     # Ganged single-window launch.
+    one = pallas_kernel.window_count(1, 1)
     np.asarray(
-        sharded_search_chunk_batch(params, mesh=mesh, chunk_per_shard=chunk_per_shard)
+        sharded_search_chunk_batch(
+            params, one, mesh=mesh, chunk_per_shard=chunk_per_shard
+        )
     )
     t0 = time.perf_counter()
     for _ in range(reps):
         out = sharded_search_chunk_batch(
-            params, mesh=mesh, chunk_per_shard=chunk_per_shard
+            params, one, mesh=mesh, chunk_per_shard=chunk_per_shard
         )
     np.asarray(out)
     dt = time.perf_counter() - t0
@@ -137,7 +140,7 @@ def sweep(max_devices: int, reps: int) -> None:
     """
     import jax
 
-    from tpu_dpow.ops import search
+    from tpu_dpow.ops import pallas_kernel, search
     from tpu_dpow.parallel import (
         make_mesh,
         replicate_params,
@@ -162,10 +165,15 @@ def sweep(max_devices: int, reps: int) -> None:
     while n <= min(max_devices, len(devices)):
         mesh = make_mesh(devices[:n])
         params = replicate_params(rows, mesh)
-        np.asarray(sharded_search_chunk_batch(params, mesh=mesh, chunk_per_shard=chunk))
+        one = pallas_kernel.window_count(1, 1)
+        np.asarray(
+            sharded_search_chunk_batch(params, one, mesh=mesh, chunk_per_shard=chunk)
+        )
         t0 = time.perf_counter()
         for _ in range(reps):
-            got = sharded_search_chunk_batch(params, mesh=mesh, chunk_per_shard=chunk)
+            got = sharded_search_chunk_batch(
+                params, one, mesh=mesh, chunk_per_shard=chunk
+            )
         np.asarray(got)
         out["launch_overhead_ms"][n] = round((time.perf_counter() - t0) / reps * 1e3, 3)
         n *= 2
@@ -201,8 +209,8 @@ def ab(n_devices: int, span: int, reps: int, out_path: str = "") -> dict:
     import jax
     import jax.numpy as jnp
 
-    from tpu_dpow.ops import search
-    from tpu_dpow.parallel import fan_search_chunk_batch
+    from tpu_dpow.ops import pallas_kernel, search
+    from tpu_dpow.parallel import fan_search_devices
 
     devices = jax.devices()[:n_devices]
     if len(devices) < n_devices:
@@ -227,10 +235,14 @@ def ab(n_devices: int, span: int, reps: int, out_path: str = "") -> dict:
     def time_fan(w: int) -> float:
         devs = devices[:w]
         per_dev = span // w
+        # Each device scans its own per_dev sub-range (the rows are dry, so
+        # which one does not change the work) and returns local offsets.
+        stacked = np.repeat(rows[None], w, axis=0)
+        one = pallas_kernel.window_count(1, 1)
 
         def fn():
-            return fan_search_chunk_batch(
-                rows, devices=devs, chunk_per_shard=per_dev
+            return fan_search_devices(
+                stacked, one, devices=devs, chunk_per_shard=per_dev
             )
 
         fn()  # compile

@@ -70,7 +70,8 @@ def run(reps: int) -> None:
 
     def null():
         return pallas_kernel.pallas_search_chunk_batch(
-            pj_tiny, sublanes=8, iters=8, nblocks=1, group=1
+            pj_tiny, pallas_kernel.window_count(1, 1),
+            sublanes=8, iters=8, nblocks=1, group=1,
         )
 
     out["null_ms"] = round(_timed(null, reps) * 1e3, 2)
@@ -83,8 +84,8 @@ def run(reps: int) -> None:
 
         def all_pad(w=windows):
             return pallas_kernel.pallas_search_chunk_batch(
-                pj_pads, sublanes=SUBLANES, iters=ITERS,
-                nblocks=w, group=GROUP,
+                pj_pads, pallas_kernel.window_count(w, w), sublanes=SUBLANES,
+                iters=ITERS, nblocks=w, group=GROUP,
             )
 
         pad_ms[windows] = _timed(all_pad, reps) * 1e3
@@ -113,8 +114,10 @@ def run(reps: int) -> None:
             row = np.stack([search.pack_params(h, difficulty, base)])
             got = int(np.asarray(
                 pallas_kernel.pallas_search_chunk_batch(
-                    jax.device_put(row, dev), sublanes=SUBLANES,
-                    iters=ITERS, nblocks=NBLOCKS * STEPS, group=GROUP,
+                    jax.device_put(row, dev),
+                    pallas_kernel.window_count(NBLOCKS * STEPS, NBLOCKS * STEPS),
+                    sublanes=SUBLANES, iters=ITERS, nblocks=NBLOCKS * STEPS,
+                    group=GROUP,
                 )
             )[0])
             if got != int(search.SENTINEL):

@@ -114,7 +114,8 @@ def main() -> None:
         t0 = time.perf_counter()
         jax.make_jaxpr(
             lambda p: pk.pallas_search_chunk_batch.__wrapped__(
-                p, sublanes=s, iters=i, nblocks=nb, group=g, unroll=True)
+                p, pk.window_count(nb, nb), sublanes=s, iters=i, nblocks=nb,
+                group=g, unroll=True)
         )(params)
         print(json.dumps({"bench": "kernel_trace_time", "mode": label,
                           "trace_s": round(time.perf_counter() - t0, 2)}))
@@ -135,7 +136,8 @@ def main() -> None:
 
         def launch():
             return pk.pallas_search_chunk_batch(
-                pj, sublanes=s, iters=i, nblocks=nb, group=g)
+                pj, pk.window_count(nb, nb), sublanes=s, iters=i, nblocks=nb,
+                group=g)
 
         t0 = time.perf_counter()
         np.asarray(launch())

@@ -277,16 +277,19 @@ async def serve_once(name: str, extra: list, check=None) -> None:
 def check_mesh(backend) -> None:
     import numpy as np
 
+    from tpu_dpow.ops import pallas_kernel
     from tpu_dpow.parallel import replicate_params, sharded_search_chunk_batch
 
     ids = {d.id for d in backend.mesh.devices.flat}
     if len(ids) != 4:
         raise SmokeFailure(f"mesh spans devices {sorted(ids)}, not 4 distinct")
+    bound = backend.nblocks * backend.run_steps  # the engine's chunked program
     text = sharded_search_chunk_batch.lower(
         replicate_params(np.zeros((backend.max_batch, 12), np.uint32), backend.mesh),
-        mesh=backend.mesh, chunk_per_shard=backend.chunk_per_shard,
+        pallas_kernel.window_count(bound, bound), mesh=backend.mesh,
+        chunk_per_shard=backend.chunk_per_shard * backend.run_steps,
         kernel=backend.kernel, sublanes=backend.sublanes, iters=backend.iters,
-        nblocks=backend.nblocks, group=backend.group,
+        nblocks=bound, group=backend.group,
     ).compile().as_text()
     if "all-reduce" not in text:
         raise SmokeFailure("compiled gang program has no all-reduce (pmin)")

@@ -528,8 +528,9 @@ def test_persistent_warm_engine_never_compiles_on_the_dispatch_path():
 
 
 def make_pallas_backend(**kw):
-    """The plain chunked Pallas path (interpreted) at a tiny geometry: a
-    window is 8*128*2 nonces, a launch 2 windows per step."""
+    """The chunked Pallas path (interpreted; plain unless ``kw`` names a
+    gang) at a tiny geometry: a window is 8*128*2 nonces, a launch 2
+    windows per step."""
     return JaxWorkBackend(
         kernel="pallas", interpret=True, sublanes=8, iters=2, nblocks=2,
         group=1, **kw,
@@ -543,14 +544,19 @@ def _difficulty_for_steps(b, steps):
     return int((1 - p) * 2**64)
 
 
-def test_pallas_ladder_holds_one_program_per_batch():
-    """On the plain chunked Pallas path the window count is a runtime
-    operand: the warm ladder is one program per batch size, cold until
-    batch 1 (setup) and max_batch (the warm task) are compiled, and any
-    run length runs on a warm batch's program."""
+@pytest.mark.parametrize(
+    "gang", [{}, {"devices": 1}, {"mesh_devices": 1}], ids=["plain", "fan", "mesh"]
+)
+def test_pallas_ladder_holds_one_program_per_batch(gang):
+    """On every chunked Pallas path (plain, fan, mesh) the window count is
+    a runtime operand: the warm ladder is one program per batch size, cold
+    until batch 1 (setup) and max_batch (the warm task) are compiled, and
+    any run length runs on a warm batch's program."""
 
     async def run():
-        b = make_pallas_backend(warm_shapes=True, max_batch=4, run_steps=16)
+        b = make_pallas_backend(
+            warm_shapes=True, max_batch=4, run_steps=16, **gang
+        )
         assert b._step_counts() == [1, 4, 16]
         assert b.cold_shapes() == [(1, None), (4, None)]
         await b.setup()

@@ -12,6 +12,7 @@ pmap fan (parallel/fan_search.py).
 """
 
 import hashlib
+import math
 import secrets
 
 import jax
@@ -19,13 +20,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_dpow.ops import search
+from tpu_dpow.ops import pallas_kernel, search
 from tpu_dpow.parallel import (
     BATCH_AXIS,
     NONCE_AXIS,
-    expected_steps,
-    fan_search_chunk_batch,
-    fan_search_run,
+    fan_search_devices,
+    fan_search_run_controlled,
     make_mesh,
     replicate_params,
     sharded_search_chunk_batch,
@@ -34,6 +34,8 @@ from tpu_dpow.parallel import (
 from tpu_dpow.utils import nanocrypto as nc
 
 CHUNK = 256  # tiny per-shard windows: tests stay fast on CPU
+MASK64 = (1 << 64) - 1
+SENTINEL = int(search.SENTINEL)
 
 #: Each gang test runs once per implementation.
 GANG_IMPLS = [
@@ -52,17 +54,48 @@ def _devs(n=None):
     return devices if n is None else devices[:n]
 
 
+def _all_windows(kw) -> np.int32:
+    nblocks = kw.get("nblocks", 1)
+    return pallas_kernel.window_count(nblocks, nblocks)
+
+
+def _base(row) -> int:
+    return (int(row[search.BASE_HI]) << 32) | int(row[search.BASE_LO])
+
+
+def _fan_stack(rows, n: int, chunk_per_shard: int) -> np.ndarray:
+    """uint32[B,12] rows → the fan's [n,B,12], device d based d * chunk
+    past each row's own base (the mesh's shard interleave)."""
+    stacked = np.repeat(np.asarray(rows, np.uint32)[None], n, axis=0)
+    for d in range(n):
+        for row in stacked[d]:
+            base = (_base(row) + d * chunk_per_shard) & MASK64
+            row[search.BASE_LO], row[search.BASE_HI] = base & 0xFFFFFFFF, base >> 32
+    return stacked
+
+
 def gang_chunk_batch(impl, rows, *, chunk_per_shard, n_devices=None, **kw):
-    """One ganged window launch via either implementation → offsets[B]."""
+    """One ganged window launch via either implementation → offsets[B]. The
+    fan returns each device's local offset; the min-offset election here is
+    the one the engine makes on the host (_apply_fan_rows)."""
     devices = _devs(n_devices)
+    windows = _all_windows(kw)
     if impl == "fan":
-        return fan_search_chunk_batch(
-            rows, devices=devices, chunk_per_shard=chunk_per_shard, **kw
+        n = len(devices)
+        local = fan_search_devices(
+            _fan_stack(rows, n, chunk_per_shard), windows, devices=devices,
+            chunk_per_shard=chunk_per_shard, **kw
         )
+        glob = [
+            [SENTINEL if off == SENTINEL else d * chunk_per_shard + off
+             for off in map(int, local[d])]
+            for d in range(n)
+        ]
+        return np.asarray(np.min(glob, axis=0), dtype=np.uint32)
     mesh = make_mesh(devices)
     return np.asarray(
         sharded_search_chunk_batch(
-            replicate_params(rows, mesh), mesh=mesh,
+            replicate_params(rows, mesh), windows, mesh=mesh,
             chunk_per_shard=chunk_per_shard, **kw
         )
     )
@@ -70,14 +103,28 @@ def gang_chunk_batch(impl, rows, *, chunk_per_shard, n_devices=None, **kw):
 
 def gang_run(impl, rows, active=None, *, chunk_per_shard, max_steps,
              n_devices=None, **kw):
-    """Multi-step ganged search via either implementation → (lo, hi)[B]."""
+    """Multi-step ganged search via either implementation → (lo, hi)[B].
+    The fan runs its controlled loop at slot 0 (no control block: its one
+    poll reads dead zeros), window k of device d covering
+    ``base + (k * n + d) * chunk``; the host elects each row's earliest
+    nonce past its base."""
     devices = _devs(n_devices)
     if impl == "fan":
-        lo, hi = fan_search_run(
-            rows, active, devices=devices, chunk_per_shard=chunk_per_shard,
-            max_steps=max_steps, **kw
+        n = len(devices)
+        lo_d, hi_d = fan_search_run_controlled(
+            _fan_stack(rows, n, chunk_per_shard), 0, devices=devices,
+            chunk_per_shard=chunk_per_shard, max_steps=max_steps,
+            poll_steps=max_steps, stride=chunk_per_shard * n, active=active,
+            **kw
         )
-        return np.asarray(lo), np.asarray(hi)
+        out = np.full((2, len(rows)), 0xFFFFFFFF, dtype=np.uint32)
+        for i, row in enumerate(np.asarray(rows)):
+            nonces = [(int(hi_d[d, i]) << 32) | int(lo_d[d, i]) for d in range(n)]
+            found = [x for x in nonces if x != MASK64]
+            if found:
+                best = min(found, key=lambda x: (x - _base(row)) & MASK64)
+                out[:, i] = best & 0xFFFFFFFF, best >> 32
+        return out[0], out[1]
     mesh = make_mesh(devices)
     lo, hi = sharded_search_run(
         replicate_params(rows, mesh),
@@ -210,7 +257,8 @@ def test_batch_rows_on_partial_gang(gang):
         m = make_mesh(jax.devices(), batch_shards=4)
         out = np.asarray(
             sharded_search_chunk_batch(
-                replicate_params(rows, m), mesh=m, chunk_per_shard=CHUNK
+                replicate_params(rows, m), pallas_kernel.window_count(1, 1),
+                mesh=m, chunk_per_shard=CHUNK,
             )
         )
     assert all(int(o) <= 3 for o in out)
@@ -222,7 +270,9 @@ def test_gang_search_run_to_solution(gang):
     h = secrets.token_bytes(32)
     diff = 0xFFFC000000000000  # ~2^14 expected hashes: a few tiny windows
     p = _params(h, diff, secrets.randbits(64))
-    steps = expected_steps(diff, chunk_per_shard=CHUNK, n_nonce=len(jax.devices()))
+    # Median number of ganged windows to a solution at this difficulty.
+    median_hashes = math.log(2) / ((2**64 - diff) / 2**64)
+    steps = max(1, math.ceil(median_hashes / (CHUNK * len(jax.devices()))))
     lo, hi = gang_run(
         gang, p, chunk_per_shard=CHUNK, max_steps=max(steps * 8, 64)
     )
@@ -381,7 +431,8 @@ def test_multihost_mesh_single_process_runs_search():
     p = _params(h, diff, base)
     out = np.asarray(
         sharded_search_chunk_batch(
-            replicate_params(p, mesh), mesh=mesh, chunk_per_shard=CHUNK
+            replicate_params(p, mesh), pallas_kernel.window_count(1, 1),
+            mesh=mesh, chunk_per_shard=CHUNK,
         )
     )
     off = int(out[0])

@@ -109,9 +109,10 @@ def test_controlled_loop_without_commands_matches_plain_run():
     rows = np.stack([search.pack_params(h, diff, base)])
     c = ctl.LaunchControl(1, clock=TickClock())
     nonce = controlled_run(rows, c, max_steps=4096, poll_steps=4)
-    lo_p, hi_p = runloop.search_run_batch(
-        jnp.asarray(rows), jnp.array([True]), max_steps=4096, kernel="xla",
-        sublanes=8, iters=2,
+    # Slot 0 has no control block: the same loop, polling dead zeros.
+    lo_p, hi_p = runloop.search_run_batch_controlled(
+        jnp.asarray(rows), jnp.array([True]), jnp.uint32(0), max_steps=4096,
+        poll_steps=4, kernel="xla", sublanes=8, iters=2,
     )
     plain = (int(hi_p[0]) << 32) | int(lo_p[0])
     assert nonce == plain == planted

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_dpow.ops import pallas_kernel, search
+from tpu_dpow.ops import pallas_kernel, runloop, search
 
 RNG = np.random.default_rng(42)
 
@@ -66,14 +66,21 @@ def test_search_chunk_batch_matches_single():
         assert batch[i] == single
 
 
+ONE_WINDOW = pallas_kernel.window_count(1, 1)
+
+
+def _pallas_one_row(params, **kw) -> int:
+    """One row through the batched kernel at B = 1, one window."""
+    return int(pallas_kernel.pallas_search_chunk_batch(
+        params[None], ONE_WINDOW, interpret=True, **kw)[0])
+
+
 def test_pallas_interpret_matches_jnp():
     h = RNG.bytes(32)
     params = jnp.asarray(search.pack_params(h, EASY, base=31337))
     n = pallas_kernel.chunk_size(8, 16)
     want = int(search.search_chunk(params, chunk_size=n))
-    got = int(
-        pallas_kernel.pallas_search_chunk(params, sublanes=8, iters=16, interpret=True)
-    )
+    got = _pallas_one_row(params, sublanes=8, iters=16)
     assert got == want
 
 
@@ -83,7 +90,7 @@ def test_pallas_interpret_batch():
     n = pallas_kernel.chunk_size(8, 8)
     got = np.asarray(
         pallas_kernel.pallas_search_chunk_batch(
-            jnp.asarray(params), sublanes=8, iters=8, interpret=True
+            jnp.asarray(params), ONE_WINDOW, sublanes=8, iters=8, interpret=True
         )
     )
     for i in range(3):
@@ -116,6 +123,7 @@ def test_pallas_kernel_traces_the_tile_body_once_per_group():
     sublanes = 32
     kernel = pallas_kernel.pallas_search_chunk_batch.trace(
         jax.ShapeDtypeStruct((1, search.PARAMS_LEN), jnp.uint32),
+        jax.ShapeDtypeStruct((), jnp.int32),
         sublanes=sublanes, iters=1024, nblocks=8, group=8, unroll=True,
     ).jaxpr
     lanes = jax.ShapeDtypeStruct((sublanes, 128), jnp.uint32)
@@ -137,8 +145,7 @@ def test_pallas_interpret_group_keeps_the_lowest_hit(group):
     params = jnp.asarray(search.pack_params(h, easy, base=4242))
     n = pallas_kernel.chunk_size(8, 8)
     want = int(search.search_chunk(params, chunk_size=n))
-    got = int(pallas_kernel.pallas_search_chunk(
-        params, sublanes=8, iters=8, group=group, interpret=True))
+    got = _pallas_one_row(params, sublanes=8, iters=8, group=group)
     assert got == want == first_valid_offset(h, easy, 4242, want + 1)
 
 
@@ -146,7 +153,7 @@ def test_pallas_launch_window_cap():
     h = RNG.bytes(32)
     params = jnp.asarray(search.pack_params(h, EASY, base=0))
     with pytest.raises(ValueError):
-        pallas_kernel.pallas_search_chunk(params, sublanes=1024, iters=1 << 16, interpret=True)
+        _pallas_one_row(params, sublanes=1024, iters=1 << 16)
 
 
 def test_work_hex_convention():
@@ -165,7 +172,7 @@ def test_pallas_interpret_multiblock_matches_single_window():
     params = np.stack([search.pack_params(h, EASY, base=123) for h in hashes])
     got = np.asarray(
         pallas_kernel.pallas_search_chunk_batch(
-            jnp.asarray(params),
+            jnp.asarray(params), pallas_kernel.window_count(nb, nb),
             sublanes=sub, iters=it, nblocks=nb, group=grp, interpret=True,
         )
     )
@@ -178,7 +185,8 @@ def test_pallas_interpret_multiblock_sentinel_when_dry():
     params = np.stack([search.pack_params(bytes(32), (1 << 64) - 1, base=0)])
     got = np.asarray(
         pallas_kernel.pallas_search_chunk_batch(
-            jnp.asarray(params), sublanes=8, iters=4, nblocks=3, interpret=True
+            jnp.asarray(params), pallas_kernel.window_count(3, 3),
+            sublanes=8, iters=4, nblocks=3, interpret=True,
         )
     )
     assert got[0] == search.SENTINEL
@@ -197,9 +205,10 @@ def _late_hit_row(difficulty: int, lo: int, hi: int) -> tuple:
 
 def test_pallas_runtime_window_count_matches_the_static_grid():
     """One program, its window count a runtime grid bound, called with 1x,
-    4x and 16x nblocks windows: row by row the same offsets as the static
-    grid of that many windows — a hit only in a late window, a dry row
-    (SENTINEL) and difficulty-0 pad rows — and one trace for all three."""
+    4x and 16x nblocks windows: row by row the same offsets as the jnp
+    reference scan of that many windows — a hit only in a late window, a
+    dry row (SENTINEL) and difficulty-0 pad rows — and one trace for all
+    three."""
     sub, it, nb = 8, 2, 2
     window = pallas_kernel.chunk_size(sub, it)
     counts = (nb, 4 * nb, 16 * nb)
@@ -219,7 +228,7 @@ def test_pallas_runtime_window_count_matches_the_static_grid():
         traced.append(fn._cache_size())
     assert traced[0] == traced[1] == traced[2], "a window count traced again"
     for n in counts:
-        want = np.asarray(fn(params, nblocks=n, **geometry))
+        want = [int(search.search_chunk(row, chunk_size=n * window)) for row in params]
         np.testing.assert_array_equal(got[n], want)
     sentinel = int(search.SENTINEL)
     assert [int(got[n][0]) == sentinel for n in counts] == [True, True, False]
@@ -243,9 +252,16 @@ def test_pallas_window_count_outside_the_bound_is_refused(windows):
 # -- device-resident run loop (ops/runloop.py) ---------------------------
 
 
-def test_run_batch_finds_nonce_across_windows():
-    from tpu_dpow.ops import runloop
+def _run_batch(params, active, *, max_steps, **kw):
+    """The run loop at slot 0: no control block, so its one poll reads dead
+    zeros and the loop just runs."""
+    return runloop.search_run_batch_controlled(
+        params, active, jnp.uint32(0), max_steps=max_steps,
+        poll_steps=max_steps, sublanes=8, iters=2, **kw,
+    )
 
+
+def test_run_batch_finds_nonce_across_windows():
     h = RNG.bytes(32)
     base = 7 << 20
     window = 8 * 128 * 2  # sublanes=8, iters=2
@@ -258,29 +274,19 @@ def test_run_batch_finds_nonce_across_windows():
     assert planted is not None
     difficulty = EASY
     params = jnp.stack([jnp.asarray(search.pack_params(h, difficulty, base))])
-    lo, hi = runloop.search_run_batch(
-        params, jnp.array([True]), max_steps=8, kernel="xla",
-        sublanes=8, iters=2,
-    )
+    lo, hi = _run_batch(params, jnp.array([True]), max_steps=8, kernel="xla")
     nonce = (int(hi[0]) << 32) | int(lo[0])
     assert nonce == base + planted
 
 
 def test_run_batch_respects_max_steps():
-    from tpu_dpow.ops import runloop
-
     h = RNG.bytes(32)
     params = jnp.stack([jnp.asarray(search.pack_params(h, (1 << 64) - 1, 0))])
-    lo, hi = runloop.search_run_batch(
-        params, jnp.array([True]), max_steps=3, kernel="xla",
-        sublanes=8, iters=2,
-    )
+    lo, hi = _run_batch(params, jnp.array([True]), max_steps=3, kernel="xla")
     assert int(lo[0]) == 0xFFFFFFFF and int(hi[0]) == 0xFFFFFFFF
 
 
 def test_run_batch_inactive_rows_do_not_hold_loop():
-    from tpu_dpow.ops import runloop
-
     h = RNG.bytes(32)
     rows = jnp.stack(
         [
@@ -289,41 +295,27 @@ def test_run_batch_inactive_rows_do_not_hold_loop():
             jnp.asarray(search.pack_params(bytes(32), (1 << 64) - 1, 0)),
         ]
     )
-    lo, hi = runloop.search_run_batch(
-        rows, jnp.array([True, False]), max_steps=64, kernel="xla",
-        sublanes=8, iters=2,
-    )
+    lo, hi = _run_batch(rows, jnp.array([True, False]), max_steps=64, kernel="xla")
     assert int(lo[0]) != 0xFFFFFFFF or int(hi[0]) != 0xFFFFFFFF
     assert int(lo[1]) == 0xFFFFFFFF and int(hi[1]) == 0xFFFFFFFF
 
 
 def test_run_batch_base_carry_across_64bit_wrap():
-    from tpu_dpow.ops import runloop
-
     h = RNG.bytes(32)
     window = 8 * 128 * 2
     # Base close to 2^64: the advance must wrap cleanly through zero.
     base = (1 << 64) - window - 3
     params = jnp.stack([jnp.asarray(search.pack_params(h, EASY, base))])
-    lo, hi = runloop.search_run_batch(
-        params, jnp.array([True]), max_steps=8, kernel="xla",
-        sublanes=8, iters=2,
-    )
+    lo, hi = _run_batch(params, jnp.array([True]), max_steps=8, kernel="xla")
     nonce = (int(hi[0]) << 32) | int(lo[0])
     assert ref_value(nonce, h) >= EASY
 
 
 def test_run_batch_pallas_interpret_matches_xla():
-    from tpu_dpow.ops import runloop
-
     h = RNG.bytes(32)
     params = jnp.stack([jnp.asarray(search.pack_params(h, EASY, 1234))])
-    lo_x, hi_x = runloop.search_run_batch(
-        params, jnp.array([True]), max_steps=4, kernel="xla",
-        sublanes=8, iters=2,
-    )
-    lo_p, hi_p = runloop.search_run_batch(
-        params, jnp.array([True]), max_steps=4, kernel="pallas",
-        sublanes=8, iters=2, interpret=True,
+    lo_x, hi_x = _run_batch(params, jnp.array([True]), max_steps=4, kernel="xla")
+    lo_p, hi_p = _run_batch(
+        params, jnp.array([True]), max_steps=4, kernel="pallas", interpret=True,
     )
     assert int(lo_x[0]) == int(lo_p[0]) and int(hi_x[0]) == int(hi_p[0])

@@ -59,19 +59,21 @@ def _params(batch, sharding):
     return jax.ShapeDtypeStruct((batch, PARAMS_LEN), jnp.uint32, sharding=sharding)
 
 
+def _windows(sharding):
+    return jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
+
+
 @pytest.mark.parametrize("batch", [1, 16])
-@pytest.mark.parametrize("windows", ["static", "runtime"])
+@pytest.mark.parametrize("windows", ["loop", "runtime"])
 def test_pallas_chunk_batch_compiles(one_chip, batch, windows):
-    """``static``: the grid the fan, mesh and run loop compile. ``runtime``:
-    the engine's plain path, its window count an int32 operand under the
-    static bound of run_steps (16) launches of nblocks windows."""
+    """Every launch's window count is an int32 operand. ``loop``: the run
+    loop's per-window program, its bound nblocks. ``runtime``: the engine's
+    chunked launch, its bound run_steps (16) launches of nblocks windows."""
     geometry = dict(GEOMETRY)
-    args = [_params(batch, one_chip)]
     if windows == "runtime":
         geometry["nblocks"] *= 16
-        args.append(jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
     compiled = pallas_kernel.pallas_search_chunk_batch.lower(
-        *args, unroll=True, **geometry
+        _params(batch, one_chip), _windows(one_chip), unroll=True, **geometry
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -86,17 +88,29 @@ def test_persistent_controlled_run_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_mesh_gang_compiles_on_four_chips(topo, monkeypatch):
+@pytest.mark.parametrize("bound", ["window", "run_steps"])
+def test_mesh_gang_compiles_on_four_chips(topo, monkeypatch, bound):
+    """``window``: the bound of one launch's nblocks windows (what
+    sharded_search_run launches). ``run_steps``: the engine's chunked mesh
+    launch, its bound run_steps launches, capped as the engine caps it so
+    the four shards' offsets stay below 2^31."""
     from tpu_dpow.parallel import BATCH_AXIS, make_mesh, sharded_search_chunk_batch
 
     # The gang has no unroll argument: steer the default it would take on
     # a chip (this process's default backend is the CPU).
     monkeypatch.setattr(pallas_kernel, "_default_unroll", lambda interpret: True)
     mesh = make_mesh(topo.devices[:4])
-    chunk = GEOMETRY["sublanes"] * 128 * GEOMETRY["iters"] * GEOMETRY["nblocks"]
+    geometry = dict(GEOMETRY)
+    chunk = pallas_kernel.chunk_size(geometry["sublanes"], geometry["iters"])
+    chunk *= geometry["nblocks"]
+    if bound == "run_steps":
+        steps = min(16, ((1 << 31) - 1) // (chunk * 4))
+        geometry["nblocks"] *= steps
+        chunk *= steps
     compiled = sharded_search_chunk_batch.lower(
         _params(16, NamedSharding(mesh, P(BATCH_AXIS, None))),
-        mesh=mesh, chunk_per_shard=chunk, kernel="pallas", **GEOMETRY,
+        _windows(NamedSharding(mesh, P())),
+        mesh=mesh, chunk_per_shard=chunk, kernel="pallas", **geometry,
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text

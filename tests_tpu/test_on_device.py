@@ -65,7 +65,8 @@ def test_pallas_matches_xla_scanner_on_device(tpu_device):
     chunk = sub * 128 * it
     params = np.stack([search.pack_params(h, 0xFFF0000000000000, base)])
     pall = pallas_kernel.pallas_search_chunk_batch(
-        jnp.asarray(params), sublanes=sub, iters=it
+        jnp.asarray(params), pallas_kernel.window_count(1, 1),
+        sublanes=sub, iters=it,
     )
     xla = search.search_chunk_batch(jnp.asarray(params), chunk_size=chunk)
     assert int(np.asarray(pall)[0]) == int(np.asarray(xla)[0])
@@ -86,17 +87,46 @@ def test_pallas_multiblock_early_exit_on_device(tpu_device):
     diff = _plant(h, base + offset)
     params = np.stack([search.pack_params(h, diff, base)])
     out = pallas_kernel.pallas_search_chunk_batch(
-        jnp.asarray(params), sublanes=sub, iters=it, nblocks=nb, group=4
+        jnp.asarray(params), pallas_kernel.window_count(nb, nb),
+        sublanes=sub, iters=it, nblocks=nb, group=4,
     )
     got = int(np.asarray(out)[0])
     assert got <= offset
     assert _plant(h, base + got) >= diff
 
 
+def _runtime_count_rows(sub: int, it: int, last: int):
+    """Rows for the runtime-count checks: one planted in window ``last - 1``
+    (of ``sub * 128 * it`` nonces), a dry row, two difficulty-0 pads."""
+    from tpu_dpow.ops import search
+
+    window = sub * 128 * it
+    h = secrets.token_bytes(32)
+    base = 5 << 30
+    offset = (last - 1) * window + 123
+    diff = _plant(h, base + offset)
+    rows = np.stack([
+        search.pack_params(h, diff, base),
+        search.pack_params(h, (1 << 64) - 1, base),
+        search.pack_params(bytes(32), 0, 0),
+        search.pack_params(bytes(32), 0, 0),
+    ])
+    return rows, h, base, offset, diff
+
+
+def _reference_offsets(rows, span: int) -> np.ndarray:
+    """The XLA scanner's lowest valid offset per row over ``span`` nonces."""
+    import jax.numpy as jnp
+
+    from tpu_dpow.ops import search
+
+    return np.asarray(search.search_chunk_batch(jnp.asarray(rows), chunk_size=span))
+
+
 def test_pallas_runtime_window_count_on_device(tpu_device):
     """The engine's plain-path launch: one program whose window count is a
     runtime grid bound returns, at 1x, 4x and 16x nblocks windows, row by
-    row what the static grid of that many windows returns — a row planted
+    row what the XLA scanner returns over that many windows — a row planted
     in the last window, a dry row, difficulty-0 pads — traced once."""
     import jax.numpy as jnp
 
@@ -105,17 +135,8 @@ def test_pallas_runtime_window_count_on_device(tpu_device):
     sub, it, nb = 8, 8, 2
     window = sub * 128 * it
     counts = (nb, 4 * nb, 16 * nb)
-    h = secrets.token_bytes(32)
-    base = 5 << 30
-    offset = (counts[2] - 1) * window + 123  # the last window
-    diff = _plant(h, base + offset)
-    rows = [
-        search.pack_params(h, diff, base),
-        search.pack_params(h, (1 << 64) - 1, base),
-        search.pack_params(bytes(32), 0, 0),
-        search.pack_params(bytes(32), 0, 0),
-    ]
-    params = jnp.asarray(np.stack(rows))
+    rows, h, base, offset, diff = _runtime_count_rows(sub, it, counts[2])
+    params = jnp.asarray(rows)
     geometry = dict(sublanes=sub, iters=it, group=4)
     fn = pallas_kernel.pallas_search_chunk_batch
     got, traced = {}, []
@@ -126,8 +147,7 @@ def test_pallas_runtime_window_count_on_device(tpu_device):
         traced.append(fn._cache_size())
     assert traced[0] == traced[1] == traced[2]
     for n in counts:
-        want = np.asarray(fn(params, nblocks=n, **geometry))
-        np.testing.assert_array_equal(got[n], want)
+        np.testing.assert_array_equal(got[n], _reference_offsets(rows, n * window))
         assert int(got[n][1]) == search.SENTINEL
         assert int(got[n][2]) == int(got[n][3]) == 0
     hit = int(got[counts[2]][0])
@@ -146,7 +166,8 @@ def test_flagship_geometry_finds_and_validates(tpu_device):
     diff = 0xFFFFF00000000000  # ~2^20 expected: well inside one dispatch
     params = np.stack([search.pack_params(h, diff, base)])
     out = pallas_kernel.pallas_search_chunk_batch(
-        jnp.asarray(params), sublanes=32, iters=1024, nblocks=4, group=8
+        jnp.asarray(params), pallas_kernel.window_count(4, 4),
+        sublanes=32, iters=1024, nblocks=4, group=8,
     )
     got = int(np.asarray(out)[0])
     assert got != int(search.SENTINEL), "no hit in 16.7M nonces at 2^20 difficulty"
@@ -199,7 +220,8 @@ def test_widened_grid_deep_hit_on_device(tpu_device):
     diff = values[argmax]  # unique hit in range, by construction
     params = np.stack([search.pack_params(h, diff, base)])
     out = pallas_kernel.pallas_search_chunk_batch(
-        jnp.asarray(params), sublanes=sub, iters=it, nblocks=nb, group=4
+        jnp.asarray(params), pallas_kernel.window_count(nb, nb),
+        sublanes=sub, iters=it, nblocks=nb, group=4,
     )
     assert int(np.asarray(out)[0]) == argmax
 
@@ -217,7 +239,8 @@ def test_difficulty_zero_pad_rows_cost_nothing_and_report_zero(tpu_device):
     params = np.stack([real] + [pad] * 7)
     out = np.asarray(
         pallas_kernel.pallas_search_chunk_batch(
-            jnp.asarray(params), sublanes=8, iters=16, nblocks=8, group=8
+            jnp.asarray(params), pallas_kernel.window_count(8, 8),
+            sublanes=8, iters=16, nblocks=8, group=8,
         )
     )
     assert all(int(o) == 0 for o in out[1:])  # pads hit instantly
@@ -231,7 +254,7 @@ def test_sharded_pallas_path_on_device(tpu_device):
     dryrun only ever see the interpret/XLA lowering of this code."""
     import jax
 
-    from tpu_dpow.ops import search
+    from tpu_dpow.ops import pallas_kernel, search
     from tpu_dpow.parallel import (
         make_mesh, replicate_params, sharded_search_chunk_batch,
         sharded_search_run,
@@ -250,6 +273,7 @@ def test_sharded_pallas_path_on_device(tpu_device):
 
     out = sharded_search_chunk_batch(
         replicate_params(params, mesh),
+        pallas_kernel.window_count(nblocks, nblocks),
         mesh=mesh, chunk_per_shard=chunk, kernel="pallas",
         sublanes=sublanes, iters=iters, nblocks=nblocks, group=8,
     )
@@ -268,6 +292,41 @@ def test_sharded_pallas_path_on_device(tpu_device):
     nonce = (int(np.asarray(hi)[0]) << 32) | int(np.asarray(lo)[0])
     assert nonce != (1 << 64) - 1, "run-mode gang found nothing in 16.7M nonces"
     assert _plant(h, nonce) >= diff
+
+
+@pytest.mark.parametrize("gang", ["fan", "mesh"])
+def test_gang_runtime_window_count_on_device(gang):
+    """The engine's chunked fan (devices=1) and mesh (mesh_devices=1)
+    launches: one program per batch, the window count a runtime operand,
+    at 1x, 4x and 16x nblocks windows return row by row what the XLA
+    scanner returns over the same span — a row planted in the last window,
+    a dry row, difficulty-0 pads."""
+    from tpu_dpow.backend.jax_backend import JaxWorkBackend
+    from tpu_dpow.ops import search
+
+    sub, it, nb, runs = 8, 8, 2, (1, 4, 16)
+    gang_kw = {"devices": 1} if gang == "fan" else {"mesh_devices": 1}
+    b = JaxWorkBackend(
+        sublanes=sub, iters=it, nblocks=nb, group=4, run_steps=runs[-1],
+        **gang_kw,
+    )
+    assert b.kernel == "pallas" and b._program(4, 4) == (4, None)
+    rows, h, base, offset, diff = _runtime_count_rows(sub, it, nb * runs[-1])
+    window = sub * 128 * it
+    for steps in runs:
+        lo, hi = b._launch(rows, steps)
+        lo, hi = np.asarray(lo).reshape(-1), np.asarray(hi).reshape(-1)
+        want = _reference_offsets(rows, steps * nb * window)
+        for i, row_off in enumerate(want):
+            got = (int(hi[i]) << 32) | int(lo[i])
+            if row_off == 0xFFFFFFFF:
+                assert got == (1 << 64) - 1, (steps, i)
+            else:
+                row_base = (int(rows[i][search.BASE_HI]) << 32) | int(
+                    rows[i][search.BASE_LO])
+                assert got == row_base + int(row_off), (steps, i)
+    hit = (int(hi[0]) << 32) | int(lo[0])
+    assert hit <= base + offset and _plant(h, hit) >= diff
 
 
 def test_backend_run_mode_and_warm_shapes_on_device():

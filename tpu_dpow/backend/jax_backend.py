@@ -445,16 +445,6 @@ class JaxWorkBackend(WorkBackend):
                 "(the pmap fan) for persistent multi-chip search"
             )
         self.run_mode = run_mode
-        # On the plain chunked Pallas path the window count is a runtime
-        # operand of the kernel (pallas_kernel.window_count), so one compiled
-        # program per batch size serves every run length. The fan, mesh,
-        # persistent and XLA paths compile one program per (batch, steps).
-        self._runtime_windows = (
-            self.kernel == "pallas"
-            and self.fan is None
-            and self.mesh is None
-            and run_mode == "chunked"
-        )
         if control_poll_steps < 0:
             raise WorkError("control_poll_steps must be >= 0 (0 = auto)")
         # Poll cadence tradeoff: each poll is an io_callback (a host touch)
@@ -719,12 +709,13 @@ class JaxWorkBackend(WorkBackend):
             self.max_batch > 1 or self.run_steps > 1
         ):
             # With warming ON (TPU), setup() returns after the single
-            # self-test compile; the rest of the ladder — max_batch, and on
-            # the static-grid paths the (1, steps) run-mode rungs — compiles
-            # in the background. Each costs seconds, and a client blocked in
-            # setup() serves nothing; a request arriving before its rung is
-            # warm just runs at the largest warmed step count (more round
-            # trips, still correct — see _pick_shape).
+            # self-test compile; the rest of the ladder — max_batch, and
+            # where programs are keyed by run length (_program) the
+            # (1, steps) rungs — compiles in the background. Each costs
+            # seconds, and a client blocked in setup() serves nothing; a
+            # request arriving before its rung is warm just runs at the
+            # largest warmed step count (more round trips, still correct —
+            # see _pick_shape).
             self._warm_task = asyncio.ensure_future(self._warmup_loop())
 
     async def generate(self, request: WorkRequest) -> str:
@@ -1434,9 +1425,13 @@ class JaxWorkBackend(WorkBackend):
 
     def _program(self, b: int, steps: int) -> tuple:
         """The compiled launch program a (batch, steps) launch runs, as the
-        warm set keys it: (b, steps), or (b, None) — one program for every
-        run length — where the window count is a runtime operand."""
-        return (b, None) if self._runtime_windows else (b, steps)
+        warm set keys it. A chunked Pallas launch — plain, fan or mesh —
+        takes its window count at run time, so one program per batch serves
+        every run length: (b, None). Persistent mode's while_loop and the
+        XLA scanner compile each run length: (b, steps)."""
+        if self.kernel == "pallas" and self.run_mode == "chunked":
+            return b, None
+        return b, steps
 
     def _mark_warm(self, b: int, steps: int) -> None:
         self._warm.add(self._program(b, steps))
@@ -1467,8 +1462,8 @@ class JaxWorkBackend(WorkBackend):
         warmed_bs = sorted({b for b, _ in self._warm})
         fitting = [b for b in warmed_bs if b >= b_want]
         b = fitting[0] if fitting else warmed_bs[-1]
-        if self._runtime_windows:
-            return b, steps_want  # the batch's one program runs any length
+        if self._program(b, steps_want) in self._warm:
+            return b, steps_want
         cands = [s for bb, s in self._warm if bb == b and s <= steps_want]
         steps = max(cands) if cands else steps_want  # compile inline if cold
         return b, steps
@@ -1476,17 +1471,15 @@ class JaxWorkBackend(WorkBackend):
     def _step_counts(self) -> list:
         """The quantized run lengths the engine may emit (ascending).
 
-        On the fan, mesh, persistent and XLA paths each distinct count is a
-        separate compile (_program), so the default ladder is powers of
-        four — few enough to warm at setup, granular enough that easy
+        The default ladder is powers of four: granular enough that easy
         difficulties return to the host (and thus to fresh arrivals and
-        cancels) after one or two windows. On the plain chunked Pallas path
-        the count is a runtime operand and costs no compile. The
-        ``step_ladder="x2"`` option halves the quantization step (base
-        difficulty then launches 2 windows instead of 4 — less span to
-        drain past the hit), at the cost of ~2x the warm compiles where
-        counts compile; which wins is an on-chip measurement
-        (benchmarks/latency.py A/B).
+        cancels) after one or two windows. On the chunked Pallas path the
+        count is a runtime operand and costs no compile; on the XLA path
+        each count is a separate compile (_program), few enough to warm at
+        setup. The ``step_ladder="x2"`` option halves the quantization step
+        (base difficulty then launches 2 windows instead of 4 — less span
+        to drain past the hit), at the cost of ~2x the XLA warm compiles;
+        which wins is an on-chip measurement (benchmarks/latency.py A/B).
         """
         if self.run_mode == "persistent":
             # One steerable mega-shape (plus the singleton the setup probe
@@ -1650,6 +1643,13 @@ class JaxWorkBackend(WorkBackend):
         if self.run_mode == "persistent":
             return self._launch_persistent(params_batch, steps, slot, devices)
         nblocks = self.nblocks * steps
+        # The launch's program (_program): on Pallas one per batch, the
+        # window count a runtime operand under the static bound of
+        # run_steps; the XLA scanner's chunk is static, so there the bound
+        # is this launch.
+        bound = self.nblocks * (self.run_steps if self.kernel == "pallas" else steps)
+        windows = pallas_kernel.window_count(nblocks, bound)
+        chunk_bound = pallas_kernel.chunk_size(self.sublanes, self.iters) * bound
         if self.fan is not None:
             from ..parallel import fan_search_devices
 
@@ -1663,12 +1663,13 @@ class JaxWorkBackend(WorkBackend):
             with obs.span("dpow.launch.wait"):
                 offs = fan_search_devices(
                     params_batch,
+                    windows,
                     devices=devs,
-                    chunk_per_shard=span_dev,
+                    chunk_per_shard=chunk_bound,
                     kernel=self.kernel,
                     sublanes=self.sublanes,
                     iters=self.iters,
-                    nblocks=nblocks,
+                    nblocks=bound,
                     group=self.group,
                     interpret=self.interpret,
                 )
@@ -1685,12 +1686,13 @@ class JaxWorkBackend(WorkBackend):
                 offs = np.asarray(
                     sharded_search_chunk_batch(
                         replicate_params(params_batch, self.mesh),
+                        windows,
                         mesh=self.mesh,
-                        chunk_per_shard=self.chunk_per_shard * steps,
+                        chunk_per_shard=chunk_bound,
                         kernel=self.kernel,
                         sublanes=self.sublanes,
                         iters=self.iters,
-                        nblocks=nblocks,
+                        nblocks=bound,
                         group=self.group,
                         interpret=self.interpret,
                     )
@@ -1701,12 +1703,9 @@ class JaxWorkBackend(WorkBackend):
         with obs.span("dpow.launch.dispatch"):
             pj = jax.device_put(params_batch, self.device)
             if self.kernel == "pallas":
-                # One program per batch (_runtime_windows): the window count
-                # is a runtime operand under the static bound of run_steps.
-                bound = self.nblocks * self.run_steps
                 out = pallas_kernel.pallas_search_chunk_batch(
                     pj,
-                    pallas_kernel.window_count(nblocks, bound),
+                    windows,
                     sublanes=self.sublanes,
                     iters=self.iters,
                     nblocks=bound,

@@ -33,7 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import blake2b
-from .search import PARAMS_LEN, SENTINEL, BASE_LO, BASE_HI, DIFF_LO, DIFF_HI
+from .search import SENTINEL, BASE_LO, BASE_HI, DIFF_LO, DIFF_HI
 
 # Default launch geometry: 32 sublanes x 128 lanes x 256 iters = 2^20 nonces
 # per launch. bench.py tunes this on real hardware; the backend overrides.
@@ -52,17 +52,16 @@ def _search_core(
     sublanes: int,
     iters: int,
     unroll: bool,
-    block_start=None,
+    block_start,
     group: int = 1,
 ) -> jnp.ndarray:
-    """Shared kernel body: scan sublanes*128*iters offsets → best offset.
+    """The kernel body: scan sublanes*128*iters offsets → best offset.
 
-    ``block_start`` (uint32, optional) shifts the whole window — used by the
-    multi-block grid so sequential grid steps cover consecutive windows
-    within one dispatch. ``group`` tiles are scanned per early-exit check:
-    the found-flag ``lax.cond`` costs real scalar-pipeline time, so checking
-    every tile taxes throughput; checking every ``group`` bounds the
-    post-hit overshoot to ``group`` tiles instead.
+    ``block_start`` (uint32) shifts the whole window, so sequential grid
+    steps cover consecutive windows within one dispatch. ``group`` tiles are
+    scanned per early-exit check: the found-flag ``lax.cond`` costs real
+    scalar-pipeline time, so checking every tile taxes throughput; checking
+    every ``group`` bounds the post-hit overshoot to ``group`` tiles instead.
     """
     tile = sublanes * 128
     if tile * iters >= 1 << 31:
@@ -73,8 +72,7 @@ def _search_core(
         lax.broadcasted_iota(jnp.uint32, (sublanes, 128), 0) * np.uint32(128)
         + lax.broadcasted_iota(jnp.uint32, (sublanes, 128), 1)
     )
-    if block_start is not None:
-        lane = lane + block_start
+    lane = lane + block_start
     msg = [get_param(i) for i in range(8)]
     diff = (get_param(DIFF_LO), get_param(DIFF_HI))
     base_lo = get_param(BASE_LO)
@@ -105,30 +103,10 @@ def _search_core(
     return jnp.where(best == _NOT_FOUND_I32, SENTINEL, best.astype(jnp.uint32))
 
 
-def _kernel_single(
-    params_ref, out_ref, *, sublanes: int, iters: int, unroll: bool, group: int
-):
-    out_ref[0] = _search_core(
-        lambda i: params_ref[i], sublanes, iters, unroll, group=group
-    )
-
-
-def _kernel_batched(
-    params_ref, out_ref, *, sublanes: int, iters: int, unroll: bool, group: int
-):
-    # The whole (B, 12) params array and (B, 1) output live unblocked in
-    # SMEM (Mosaic rejects sub-8x128 block tiles even there); each
-    # sequential grid step indexes its own row by program_id.
-    b = pl.program_id(0)
-    out_ref[b, 0] = _search_core(
-        lambda i: params_ref[b, i], sublanes, iters, unroll, group=group
-    )
-
-
 def _kernel_blocks(
     params_ref, out_ref, *, sublanes: int, iters: int, unroll: bool, group: int
 ):
-    """Multi-window grid: grid = (B, nblocks); one dispatch, early exit.
+    """The search kernel: grid = (B, windows); one dispatch, early exit.
 
     The SMEM output is shared across sequential grid steps, so it doubles as
     the found-flag: once a block writes a real offset for request b, every
@@ -164,43 +142,12 @@ def _default_unroll(interpret: bool) -> bool:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("sublanes", "iters", "interpret", "unroll", "group")
-)
-def pallas_search_chunk(
-    params: jnp.ndarray,
-    *,
-    sublanes: int = DEFAULT_SUBLANES,
-    iters: int = DEFAULT_ITERS,
-    interpret: bool = False,
-    unroll: bool | None = None,
-    group: int = 1,
-) -> jnp.ndarray:
-    """One kernel launch scanning sublanes*128*iters nonces from params' base.
-
-    Same contract as ops/search.py::search_chunk: returns the lowest valid
-    offset as uint32, or SENTINEL if the window holds no solution.
-    """
-    if unroll is None:
-        unroll = _default_unroll(interpret)
-    kernel = functools.partial(
-        _kernel_single, sublanes=sublanes, iters=iters, unroll=unroll, group=group
-    )
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((1,), jnp.uint32),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-        interpret=interpret,
-    )(params)[0]
-
-
-@functools.partial(
     jax.jit,
     static_argnames=("sublanes", "iters", "nblocks", "interpret", "unroll", "group"),
 )
 def pallas_search_chunk_batch(
     params_batch: jnp.ndarray,
-    windows: jnp.ndarray | None = None,
+    windows: jnp.ndarray,
     *,
     sublanes: int = DEFAULT_SUBLANES,
     iters: int = DEFAULT_ITERS,
@@ -216,16 +163,13 @@ def pallas_search_chunk_batch(
     one-item-at-a-time POSTs to the native worker
     (reference client/work_handler.py:98-108) without recompiles.
 
-    ``nblocks`` > 1 scans ``nblocks`` consecutive windows per request inside
-    the one dispatch with per-request early exit between windows — the
-    persistent-kernel mode that amortizes dispatch overhead. The
-    total per-request window is ``nblocks * sublanes * 128 * iters`` nonces.
-
-    ``windows`` (an int32 scalar, from ``window_count``) makes the window
-    count a runtime grid bound instead: the launch scans ``windows``
-    windows, and ``nblocks`` is only the static upper bound the 2^31-offset
-    guard holds. One compiled program per batch size then serves every run
-    length up to ``nblocks``.
+    Each request scans ``windows`` consecutive windows of
+    ``sublanes * 128 * iters`` nonces inside the one dispatch, with early
+    exit between windows — the persistent-kernel mode that amortizes
+    dispatch overhead. ``windows`` (an int32 scalar, from ``window_count``)
+    is a runtime grid bound; ``nblocks`` is its static upper bound, the one
+    the 2^31-offset guard holds. One compiled program per batch size then
+    serves every window count up to ``nblocks``.
     """
     if unroll is None:
         unroll = _default_unroll(interpret)
@@ -234,22 +178,14 @@ def pallas_search_chunk_batch(
     if nblocks * sublanes * 128 * iters >= 1 << 31:
         raise ValueError("total launch window must stay below 2^31 nonces")
     b = params_batch.shape[0]
-    if windows is None and nblocks == 1:
-        kernel = functools.partial(
-            _kernel_batched, sublanes=sublanes, iters=iters, unroll=unroll,
-            group=group,
-        )
-        grid = (b,)
-    else:
-        kernel = functools.partial(
-            _kernel_blocks, sublanes=sublanes, iters=iters, unroll=unroll,
-            group=group,
-        )
-        grid = (b, nblocks if windows is None else windows)
+    kernel = functools.partial(
+        _kernel_blocks, sublanes=sublanes, iters=iters, unroll=unroll,
+        group=group,
+    )
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.uint32),
-        grid=grid,
+        grid=(b, windows),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         interpret=interpret,

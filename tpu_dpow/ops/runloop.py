@@ -1,27 +1,24 @@
 """Device-resident multi-window nonce search for one device.
 
 The chunked engine (backend/jax_backend.py) pays a host↔device round trip
-per window: upload the params batch, run one kernel dispatch, download the
+per launch: upload the params batch, run one kernel dispatch, download the
 offsets — against the <50 ms p50 latency budget (SURVEY.md §7 hard part #3;
 the reference's analog of this overhead is its per-work-item HTTP POST
 dialogue with the native worker, reference client/work_handler.py:104-108).
 
-``search_run_batch`` keeps the whole search on device: a ``lax.while_loop``
-launches up to ``max_steps`` consecutive windows, advances every row's
-64-bit base between windows on device, and exits as soon as every *active*
-row has a hit. One launch therefore costs one round trip regardless of how
-many windows the solution needs, while ``max_steps`` bounds the launch so
-the host still gets control back to apply cancels (a SIMD machine cannot be
-interrupted mid-dispatch — SURVEY.md §7 hard part #2).
+``search_run_batch_controlled`` keeps the whole search on device: a
+``lax.while_loop`` launches up to ``max_steps`` consecutive windows,
+advances every row's 64-bit base between windows on device, polls a host
+control channel (ops/control.py) for cancel/raise/rebase, and exits as soon
+as every *active* row has a hit. One launch therefore costs one round trip
+regardless of how many windows the solution needs — the persistent run
+mode. Slot 0 has no control block: its polls read dead zeros and the loop
+just runs.
 
-This is the single-chip sibling of parallel/mesh_search.py's
-``sharded_search_run``; both share the window contract of ops/search.py.
-
-Platform note: the chunked engine instead widens a single persistent-kernel
-grid dispatch (backend/jax_backend.py run mode), which stays one round trip
-regardless of window count at the cost of a 2^31-nonce span ceiling; the
-persistent run mode uses this loop with a control poll (ops/control.py).
-Which costs less per solve on the chip is ROADMAP A2 (not measured).
+:func:`scan_windows` is the one window scan every launch path shares: this
+loop, the pmap fan (parallel/fan_search.py) and the shard_map mesh
+(parallel/mesh_search.py). Which run mode costs less per solve on the chip
+is ROADMAP A2 (not measured).
 """
 
 from __future__ import annotations
@@ -44,6 +41,52 @@ from .search import BASE_LO, BASE_HI, SENTINEL
 UNSOLVED = (1 << 64) - 1
 
 
+def check_geometry(
+    chunk: int, *, kernel: str, sublanes: int, iters: int, nblocks: int
+) -> None:
+    """Refuse a launch whose ``chunk`` is not ``nblocks`` whole windows of
+    the kernel's geometry (the span :func:`scan_windows` is bounded by)."""
+    if chunk % nblocks:
+        raise ValueError("chunk must be a whole number of nblocks windows")
+    if kernel == "pallas" and chunk != pallas_kernel.chunk_size(sublanes, iters) * nblocks:
+        raise ValueError(
+            "pallas kernel: chunk must equal sublanes*128*iters*nblocks"
+        )
+
+
+def scan_windows(
+    params_batch: jnp.ndarray,
+    windows,
+    *,
+    kernel: str,
+    chunk: int,
+    sublanes: int,
+    iters: int,
+    nblocks: int,
+    group: int,
+    interpret: bool,
+    unroll: Optional[bool] = None,
+) -> jnp.ndarray:
+    """One launch's scan: the first ``windows`` of ``nblocks`` windows of
+    ``chunk // nblocks`` nonces per row → uint32[B] lowest valid offsets,
+    SENTINEL where dry.
+
+    ``windows`` is an int32 scalar from ``pallas_kernel.window_count``.
+    'pallas' makes it the kernel's runtime grid bound, so one program per
+    batch size serves every count; 'xla' scans the static ``chunk`` with
+    the fused jnp scanner and drops the hits past ``windows`` windows, so
+    both kernels answer for the same span.
+    """
+    if kernel == "pallas":
+        return pallas_kernel.pallas_search_chunk_batch(
+            params_batch, windows, sublanes=sublanes, iters=iters,
+            nblocks=nblocks, group=group, interpret=interpret, unroll=unroll,
+        )
+    offs = search.search_chunk_batch(params_batch, chunk_size=chunk, unroll=unroll)
+    span = jnp.asarray(windows).astype(jnp.uint32) * jnp.uint32(chunk // nblocks)
+    return jnp.where(offs < span, offs, SENTINEL)
+
+
 def run_loop_core(
     params_batch: jnp.ndarray,
     active: Optional[jnp.ndarray],
@@ -58,11 +101,11 @@ def run_loop_core(
 
     ``launch(params) -> offsets`` scans one window of ``window`` nonces per
     row; this core advances bases between windows, records first hits, and
-    exits once every active row is done. Used by both the single-chip
-    :func:`search_run_batch` and the mesh-ganged
-    :func:`tpu_dpow.parallel.sharded_search_run` so the subtle parts —
-    found-masking, pinning solved rows at their winning nonce, zeroing
-    padding rows' difficulty — live in exactly one place.
+    exits once every active row is done. Used by the single-chip
+    :func:`search_run_batch_controlled`, the fan's controlled loop and the
+    mesh-ganged :func:`tpu_dpow.parallel.sharded_search_run` so the subtle
+    parts — found-masking, pinning solved rows at their winning nonce,
+    zeroing padding rows' difficulty — live in exactly one place.
 
     With ``control_poll`` set (a traced ``(k, done) -> uint32[B, CTRL_WORDS]``
     callback — ops/control.py's io_callback wrapper), the loop becomes the
@@ -184,60 +227,6 @@ def run_loop_core(
     return lo, hi
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "max_steps", "kernel", "sublanes", "iters", "nblocks", "group",
-        "interpret", "unroll",
-    ),
-)
-def search_run_batch(
-    params_batch: jnp.ndarray,
-    active: jnp.ndarray,
-    *,
-    max_steps: int,
-    kernel: str = "pallas",
-    sublanes: int = pallas_kernel.DEFAULT_SUBLANES,
-    iters: int = pallas_kernel.DEFAULT_ITERS,
-    nblocks: int = 1,
-    group: int = 1,
-    interpret: bool = False,
-    unroll: Optional[bool] = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Scan up to ``max_steps`` windows per row in ONE device launch.
-
-    Args:
-      params_batch: uint32[B, 12] rows (ops/search.py layout).
-      active: bool[B] — False rows are batch padding: they are never
-        scanned-for and never keep the loop alive.
-      max_steps: windows per row before the host regains control.
-      kernel: 'pallas' (TPU tiles) or 'xla' (fused jnp scanner — the CPU
-        fallback/test path).
-
-    Returns:
-      (lo, hi) uint32[B] pairs — each row's absolute winning 64-bit nonce,
-      or all-ones (UNSOLVED) where ``max_steps`` windows came up dry. The
-      per-row window is ``sublanes * 128 * iters * nblocks`` nonces; rows
-      that solve early stop contributing compute via the in-kernel found
-      flag, and the loop exits once all active rows are done.
-    """
-    window = sublanes * 128 * iters * nblocks
-    if window >= 1 << 31:
-        raise ValueError("per-step window must stay below 2^31 nonces")
-
-    def launch(params: jnp.ndarray) -> jnp.ndarray:
-        if kernel == "pallas":
-            return pallas_kernel.pallas_search_chunk_batch(
-                params, sublanes=sublanes, iters=iters, nblocks=nblocks,
-                group=group, interpret=interpret, unroll=unroll,
-            )
-        return search.search_chunk_batch(params, chunk_size=window, unroll=unroll)
-
-    return run_loop_core(
-        params_batch, active, launch=launch, window=window, max_steps=max_steps
-    )
-
-
 def make_control_poll(slot, *, dev=0):
     """The traced control poll for :func:`run_loop_core`: an unordered
     ``io_callback`` into ops/control.py's slot table.
@@ -283,25 +272,39 @@ def search_run_batch_controlled(
     interpret: bool = False,
     unroll: Optional[bool] = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """:func:`search_run_batch` with a live control channel: the PERSISTENT
-    single-chip launch. Identical window contract, but the loop polls slot
-    ``slot``'s host control block every ``poll_steps`` windows and applies
-    cancel/raise/rebase mid-launch, so ``max_steps`` can span the whole
-    request (one host round trip per REQUEST) while cancel latency stays
-    one poll interval. ``slot`` is traced — one compile per (batch,
-    max_steps, poll_steps) shape, reused by every launch.
+    """Scan up to ``max_steps`` windows per row in ONE device launch — the
+    PERSISTENT single-chip launch.
+
+    Args:
+      params_batch: uint32[B, 12] rows (ops/search.py layout).
+      active: bool[B] or None — False rows are batch padding: they are
+        never scanned-for and never keep the loop alive.
+      slot: the launch's control slot, traced — one compile per (batch,
+        max_steps, poll_steps) shape, reused by every launch. The loop
+        polls its host control block every ``poll_steps`` windows and
+        applies cancel/raise/rebase mid-launch, so ``max_steps`` can span
+        the whole request while cancel latency stays one poll interval.
+      kernel: 'pallas' (TPU tiles) or 'xla' (fused jnp scanner — the CPU
+        fallback/test path).
+
+    Returns:
+      (lo, hi) uint32[B] pairs — each row's absolute winning 64-bit nonce,
+      or all-ones (UNSOLVED) where ``max_steps`` windows came up dry or the
+      row was cancelled. Each step scans ``sublanes * 128 * iters *
+      nblocks`` nonces per row through :func:`scan_windows`.
     """
     window = sublanes * 128 * iters * nblocks
     if window >= 1 << 31:
         raise ValueError("per-step window must stay below 2^31 nonces")
 
+    windows = pallas_kernel.window_count(nblocks, nblocks)
+
     def launch(params: jnp.ndarray) -> jnp.ndarray:
-        if kernel == "pallas":
-            return pallas_kernel.pallas_search_chunk_batch(
-                params, sublanes=sublanes, iters=iters, nblocks=nblocks,
-                group=group, interpret=interpret, unroll=unroll,
-            )
-        return search.search_chunk_batch(params, chunk_size=window, unroll=unroll)
+        return scan_windows(
+            params, windows, kernel=kernel, chunk=window, sublanes=sublanes,
+            iters=iters, nblocks=nblocks, group=group, interpret=interpret,
+            unroll=unroll,
+        )
 
     return run_loop_core(
         params_batch, active, launch=launch, window=window,

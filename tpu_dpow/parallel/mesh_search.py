@@ -32,7 +32,6 @@ termination is the pmin plus the host dropping the job from the next launch.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional, Sequence
 
 import jax
@@ -42,7 +41,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import pallas_kernel, runloop, search
-from ..ops.search import BASE_LO, BASE_HI, PARAMS_LEN, SENTINEL
+from ..ops.search import SENTINEL
 
 BATCH_AXIS = "batch"
 NONCE_AXIS = "nonce"
@@ -79,6 +78,7 @@ _advance_base = search.advance_base_batch
 )
 def sharded_search_chunk_batch(
     params_batch: jnp.ndarray,
+    windows: jnp.ndarray,
     *,
     mesh: Mesh,
     chunk_per_shard: int,
@@ -91,45 +91,42 @@ def sharded_search_chunk_batch(
 ) -> jnp.ndarray:
     """One ganged multi-chip launch: uint32[B,12] → uint32[B] global offsets.
 
-    Each request's window of ``chunk_per_shard * mesh.shape[NONCE_AXIS]``
-    nonces is scanned in parallel; the returned offset is relative to the
-    request's own base (SENTINEL if the whole ganged window is dry), so the
-    host loop advances bases by the *global* chunk exactly as in the
-    single-chip engine.
+    Each shard scans ``windows`` windows (an int32 scalar from
+    ``pallas_kernel.window_count``) of ``chunk_per_shard // nblocks``
+    nonces; ``chunk_per_shard`` is the static bound of ``nblocks`` windows,
+    so one program per batch size serves every window count. The ganged
+    window is ``span * mesh.shape[NONCE_AXIS]`` nonces with ``span`` the
+    shards' common scan, and shard i starts ``i * span`` past the base.
+    The returned offset is relative to the request's own base (SENTINEL if
+    the whole ganged window is dry), so the host loop advances bases by the
+    *global* span exactly as in the single-chip engine.
 
-    kernel='pallas' uses the hand-tiled TPU kernel per shard (then
-    chunk_per_shard must equal sublanes*128*iters*nblocks); 'xla' uses the
-    fused jnp scanner (runs on any backend — this is what the CPU-mesh tests
-    and the driver's virtual-device dryrun exercise).
-
-    ``nblocks``/``group`` select the persistent-kernel mode per shard: each
-    chip scans ``nblocks`` consecutive windows in ONE dispatch with
-    per-request early exit between windows (ops/pallas_kernel.py
-    _kernel_blocks), so the multi-chip gang pays the ~8 ms dispatch floor
-    once per ``nblocks`` windows — the same amortization the single-chip
-    flagship mode uses, now per shard.
+    kernel='pallas' runs the hand-tiled TPU kernel per shard, ``windows``
+    its runtime grid bound (then chunk_per_shard must equal
+    sublanes*128*iters*nblocks); 'xla' runs the fused jnp scanner (any
+    backend — what the CPU-mesh tests and the virtual-device dryrun of
+    ``__graft_entry__.py`` exercise). Either way the per-shard scan is ops/runloop.py
+    ``scan_windows``, with per-request early exit between windows, so the
+    gang pays the ~8 ms dispatch floor once per launch like the plain path.
     """
     n_nonce = mesh.shape[NONCE_AXIS]
     if chunk_per_shard * n_nonce >= 1 << 31:
         # Global offsets must stay below the int32/SENTINEL range so the
         # pmin winner reduction and uint32 return contract both hold.
         raise ValueError("global chunk (chunk_per_shard * nonce shards) must be < 2^31")
-    if kernel == "pallas" and chunk_per_shard != sublanes * 128 * iters * nblocks:
-        raise ValueError(
-            "pallas kernel: chunk_per_shard must equal sublanes*128*iters*nblocks"
-        )
+    runloop.check_geometry(
+        chunk_per_shard, kernel=kernel, sublanes=sublanes, iters=iters,
+        nblocks=nblocks,
+    )
 
-    def shard_fn(p_local: jnp.ndarray) -> jnp.ndarray:
+    def shard_fn(p_local: jnp.ndarray, windows: jnp.ndarray) -> jnp.ndarray:
         idx = lax.axis_index(NONCE_AXIS).astype(jnp.uint32)
-        span = jnp.uint32(chunk_per_shard)
-        p_local = _advance_base(p_local, idx * span)
-        if kernel == "pallas":
-            local = pallas_kernel.pallas_search_chunk_batch(
-                p_local, sublanes=sublanes, iters=iters, nblocks=nblocks,
-                group=group, interpret=interpret,
-            )
-        else:
-            local = search.search_chunk_batch(p_local, chunk_size=chunk_per_shard)
+        span = windows.astype(jnp.uint32) * jnp.uint32(chunk_per_shard // nblocks)
+        local = runloop.scan_windows(
+            _advance_base(p_local, idx * span), windows, kernel=kernel,
+            chunk=chunk_per_shard, sublanes=sublanes, iters=iters,
+            nblocks=nblocks, group=group, interpret=interpret,
+        )
         # Local offset → offset from the request's own base. SENTINEL
         # (uint32 max) stays above every reachable global offset (< 2^31),
         # so the min-election needs no special casing.
@@ -139,10 +136,10 @@ def sharded_search_chunk_batch(
     return jax.shard_map(
         shard_fn,
         mesh=mesh,
-        in_specs=P(BATCH_AXIS, None),
+        in_specs=(P(BATCH_AXIS, None), P()),
         out_specs=P(BATCH_AXIS),
         check_vma=False,  # pmin replicates the result across NONCE_AXIS
-    )(params_batch)
+    )(params_batch, windows)
 
 
 @functools.partial(
@@ -184,88 +181,19 @@ def sharded_search_run(
     """
     n_nonce = mesh.shape[NONCE_AXIS]
     global_chunk = chunk_per_shard * n_nonce
+    windows = pallas_kernel.window_count(nblocks, nblocks)
 
     def launch(params: jnp.ndarray) -> jnp.ndarray:
         return sharded_search_chunk_batch(
-            params, mesh=mesh, chunk_per_shard=chunk_per_shard, kernel=kernel,
-            sublanes=sublanes, iters=iters, nblocks=nblocks, group=group,
-            interpret=interpret,
+            params, windows, mesh=mesh, chunk_per_shard=chunk_per_shard,
+            kernel=kernel, sublanes=sublanes, iters=iters, nblocks=nblocks,
+            group=group, interpret=interpret,
         )
 
     return runloop.run_loop_core(
         params_batch, active, launch=launch, window=global_chunk,
         max_steps=max_steps,
     )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "mesh", "chunk_per_shard", "max_steps", "poll_steps", "kernel",
-        "sublanes", "iters", "nblocks", "group", "interpret",
-    ),
-)
-def sharded_search_run_controlled(
-    params_batch: jnp.ndarray,
-    active: Optional[jnp.ndarray],
-    slot: jnp.ndarray,
-    *,
-    mesh: Mesh,
-    chunk_per_shard: int,
-    max_steps: int,
-    poll_steps: int,
-    kernel: str = "xla",
-    sublanes: int = pallas_kernel.DEFAULT_SUBLANES,
-    iters: int = pallas_kernel.DEFAULT_ITERS,
-    nblocks: int = 1,
-    group: int = 1,
-    interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """:func:`sharded_search_run` with a live control channel — the
-    PERSISTENT mesh launch (the fan twin is
-    ``parallel.fan_search.fan_search_run_controlled``).
-
-    SPMD caveat (why the engine refuses mesh+persistent): on a REAL
-    multi-device mesh every device executes this program — including the
-    control poll — independently, while the host mutates the control
-    block concurrently; two devices can observe a command at different
-    poll blocks, diverge in while_loop trip count, and deadlock the next
-    collective. Safe on a one-device mesh (the gang-machinery A/B); the
-    multi-device fix is pinning the poll to one device and broadcasting
-    (``io_callback(..., sharding=)``), not yet validated on real chips.
-
-    The loop structure is identical to :func:`sharded_search_run` — the
-    while_loop sits OUTSIDE the shard_map and every window's ganged launch
-    re-applies each shard's ``idx * chunk_per_shard`` interleave offset to
-    the current request-level base — so the control channel needs no
-    per-shard staggering: a rebase rewrites the replicated base words and
-    the next window's launch shards the new region exactly as the first
-    window sharded the old one. Control polls carry ``dev=0`` (the gang is
-    one logical frontier; per-device attribution is the fan's concern).
-    """
-    n_nonce = mesh.shape[NONCE_AXIS]
-    global_chunk = chunk_per_shard * n_nonce
-
-    def launch(params: jnp.ndarray) -> jnp.ndarray:
-        return sharded_search_chunk_batch(
-            params, mesh=mesh, chunk_per_shard=chunk_per_shard, kernel=kernel,
-            sublanes=sublanes, iters=iters, nblocks=nblocks, group=group,
-            interpret=interpret,
-        )
-
-    return runloop.run_loop_core(
-        params_batch, active, launch=launch, window=global_chunk,
-        max_steps=max_steps,
-        control_poll=runloop.make_control_poll(slot),
-        poll_steps=poll_steps,
-    )
-
-
-def expected_steps(difficulty: int, *, chunk_per_shard: int, n_nonce: int) -> int:
-    """Median number of ganged windows to a solution at this difficulty."""
-    p = (2**64 - difficulty) / 2**64
-    median_hashes = math.log(2) / max(p, 1e-30)
-    return max(1, math.ceil(median_hashes / (chunk_per_shard * n_nonce)))
 
 
 def replicate_params(params_batch: np.ndarray, mesh: Mesh) -> jax.Array:
