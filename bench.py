@@ -75,7 +75,8 @@ def measure(reps: int = 8) -> dict:
 
     def launch(p):
         return pallas_kernel.pallas_search_chunk_batch(
-            p, pallas_kernel.window_count(nblocks, nblocks),
+            p, pallas_kernel.row_count(1, 1),
+            pallas_kernel.window_count(nblocks, nblocks),
             sublanes=sublanes, iters=iters, nblocks=nblocks, group=group,
         )
 

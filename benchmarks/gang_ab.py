@@ -68,6 +68,7 @@ def run(reps: int) -> None:
     chunk = sublanes * 128 * iters * nblocks
     windows = pallas_kernel.window_count(nblocks, nblocks)
     rows = np.stack([search.pack_params(bytes(32), (1 << 64) - 1, 0)])
+    n_rows = pallas_kernel.row_count(1, 1)
 
     # plain path: single-device kernel launch
     pj = jax.device_put(rows, dev)
@@ -75,8 +76,8 @@ def run(reps: int) -> None:
     def plain():
         if kernel == "pallas":
             return pallas_kernel.pallas_search_chunk_batch(
-                pj, windows, sublanes=sublanes, iters=iters, nblocks=nblocks,
-                group=group,
+                pj, n_rows, windows, sublanes=sublanes, iters=iters,
+                nblocks=nblocks, group=group,
             )
         return search.search_chunk_batch(pj, chunk_size=chunk)
 
@@ -86,7 +87,8 @@ def run(reps: int) -> None:
 
     def ganged():
         return sharded_search_chunk_batch(
-            params, windows, mesh=mesh, chunk_per_shard=chunk, kernel=kernel,
+            params, n_rows, windows, mesh=mesh, chunk_per_shard=chunk,
+            kernel=kernel,
             sublanes=sublanes, iters=iters, nblocks=nblocks, group=group,
         )
 

@@ -67,15 +67,16 @@ def run(n_devices: int, batch_shards: int, chunk_per_shard: int, reps: int) -> N
 
     # Ganged single-window launch.
     one = pallas_kernel.window_count(1, 1)
+    all_rows = pallas_kernel.row_count(batch, batch)
     np.asarray(
         sharded_search_chunk_batch(
-            params, one, mesh=mesh, chunk_per_shard=chunk_per_shard
+            params, all_rows, one, mesh=mesh, chunk_per_shard=chunk_per_shard
         )
     )
     t0 = time.perf_counter()
     for _ in range(reps):
         out = sharded_search_chunk_batch(
-            params, one, mesh=mesh, chunk_per_shard=chunk_per_shard
+            params, all_rows, one, mesh=mesh, chunk_per_shard=chunk_per_shard
         )
     np.asarray(out)
     dt = time.perf_counter() - t0
@@ -166,13 +167,13 @@ def sweep(max_devices: int, reps: int) -> None:
         mesh = make_mesh(devices[:n])
         params = replicate_params(rows, mesh)
         one = pallas_kernel.window_count(1, 1)
-        np.asarray(
-            sharded_search_chunk_batch(params, one, mesh=mesh, chunk_per_shard=chunk)
-        )
+        one_row = pallas_kernel.row_count(1, 1)
+        np.asarray(sharded_search_chunk_batch(
+            params, one_row, one, mesh=mesh, chunk_per_shard=chunk))
         t0 = time.perf_counter()
         for _ in range(reps):
             got = sharded_search_chunk_batch(
-                params, one, mesh=mesh, chunk_per_shard=chunk
+                params, one_row, one, mesh=mesh, chunk_per_shard=chunk
             )
         np.asarray(got)
         out["launch_overhead_ms"][n] = round((time.perf_counter() - t0) / reps * 1e3, 3)
@@ -242,7 +243,8 @@ def ab(n_devices: int, span: int, reps: int, out_path: str = "") -> dict:
 
         def fn():
             return fan_search_devices(
-                stacked, one, devices=devs, chunk_per_shard=per_dev
+                stacked, pallas_kernel.row_count(1, 1), one, devices=devs,
+                chunk_per_shard=per_dev,
             )
 
         fn()  # compile

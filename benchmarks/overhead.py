@@ -70,7 +70,8 @@ def run(reps: int) -> None:
 
     def null():
         return pallas_kernel.pallas_search_chunk_batch(
-            pj_tiny, pallas_kernel.window_count(1, 1),
+            pj_tiny, pallas_kernel.row_count(1, 1),
+            pallas_kernel.window_count(1, 1),
             sublanes=8, iters=8, nblocks=1, group=1,
         )
 
@@ -84,7 +85,8 @@ def run(reps: int) -> None:
 
         def all_pad(w=windows):
             return pallas_kernel.pallas_search_chunk_batch(
-                pj_pads, pallas_kernel.window_count(w, w), sublanes=SUBLANES,
+                pj_pads, pallas_kernel.row_count(16, 16),
+                pallas_kernel.window_count(w, w), sublanes=SUBLANES,
                 iters=ITERS, nblocks=w, group=GROUP,
             )
 
@@ -115,6 +117,7 @@ def run(reps: int) -> None:
             got = int(np.asarray(
                 pallas_kernel.pallas_search_chunk_batch(
                     jax.device_put(row, dev),
+                    pallas_kernel.row_count(1, 1),
                     pallas_kernel.window_count(NBLOCKS * STEPS, NBLOCKS * STEPS),
                     sublanes=SUBLANES, iters=ITERS, nblocks=NBLOCKS * STEPS,
                     group=GROUP,

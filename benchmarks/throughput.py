@@ -59,7 +59,8 @@ def sweep_jax(reps: int) -> None:
         def launch():
             if on_tpu:
                 return pallas_kernel.pallas_search_chunk_batch(
-                    pj, pallas_kernel.window_count(nblocks, nblocks),
+                    pj, pallas_kernel.row_count(pj.shape[0], pj.shape[0]),
+                    pallas_kernel.window_count(nblocks, nblocks),
                     sublanes=sublanes, iters=iters, nblocks=nblocks,
                     group=group,
                 )

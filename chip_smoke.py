@@ -286,6 +286,7 @@ def check_mesh(backend) -> None:
     bound = backend.nblocks * backend.run_steps  # the engine's chunked program
     text = sharded_search_chunk_batch.lower(
         replicate_params(np.zeros((backend.max_batch, 12), np.uint32), backend.mesh),
+        pallas_kernel.row_count(backend.max_batch, backend.max_batch),
         pallas_kernel.window_count(bound, bound), mesh=backend.mesh,
         chunk_per_shard=backend.chunk_per_shard * backend.run_steps,
         kernel=backend.kernel, sublanes=backend.sublanes, iters=backend.iters,

@@ -548,25 +548,34 @@ def _difficulty_for_steps(b, steps):
     "gang", [{}, {"devices": 1}, {"mesh_devices": 1}], ids=["plain", "fan", "mesh"]
 )
 def test_pallas_ladder_holds_one_program_per_batch(gang):
-    """On every chunked Pallas path (plain, fan, mesh) the window count is
-    a runtime operand: the warm ladder is one program per batch size, cold
-    until batch 1 (setup) and max_batch (the warm task) are compiled, and
-    any run length runs on a warm batch's program."""
+    """On every chunked Pallas path (plain, fan, mesh) the row and window
+    counts are runtime operands: the warm ladder is one program, at
+    max_batch rows, cold until the setup self-test runs it; any live row
+    count and run length then runs on it (on the plain path, with no new
+    trace of the kernel)."""
 
     async def run():
         b = make_pallas_backend(
             warm_shapes=True, max_batch=4, run_steps=16, **gang
         )
         assert b._step_counts() == [1, 4, 16]
-        assert b.cold_shapes() == [(1, None), (4, None)]
+        assert b.cold_shapes() == [(4, None)]
         await b.setup()
-        await b._warm_task
+        compiled = pallas_kernel.pallas_search_chunk_batch._cache_size()
         assert b.cold_shapes() == []
-        assert b._warm == {(1, None), (4, None)}
+        assert b._warm == {(4, None)}
         for steps in (1, 4, 16):
             assert b._steps_for(_difficulty_for_steps(b, steps)) == steps
             assert b._pick_shape(1, steps) == (1, steps)
-            assert b._pick_shape(3, steps) == (4, steps)
+            assert b._pick_shape(3, steps) == (3, steps)
+        await b._warm_task
+        assert b._warm == {(4, None)}
+        reqs = [WorkRequest(random_hash(), EASY) for _ in range(3)]
+        works = await asyncio.gather(*(b.generate(r) for r in reqs))
+        for r, w in zip(reqs, works):
+            nc.validate_work(r.block_hash, w, EASY)
+        if not gang:  # the gangs jit their own wrappers around the kernel
+            assert pallas_kernel.pallas_search_chunk_batch._cache_size() == compiled
         await b.close()
 
     asyncio.run(asyncio.wait_for(run(), 120))
@@ -574,13 +583,13 @@ def test_pallas_ladder_holds_one_program_per_batch(gang):
 
 def test_engine_launch_programs_gauge_counts_the_warm_set():
     """dpow_engine_launch_programs reads the programs the engine holds:
-    2 on the plain Pallas path, one per (batch, steps) on a static grid."""
+    1 on the plain Pallas path, one per (batch, steps) on a static grid."""
 
     async def run():
         b = make_pallas_backend(warm_shapes=True, max_batch=4, run_steps=16)
         await b.setup()
         await b._warm_task
-        assert b._m_programs.value() == 2
+        assert b._m_programs.value() == 1
         await b.close()
         b = make_backend(warm_shapes=True, max_batch=4, run_steps=16)
         await b.setup()
@@ -592,24 +601,36 @@ def test_engine_launch_programs_gauge_counts_the_warm_set():
 
 
 def test_engine_launches_counter_by_batch_and_steps():
-    """dpow_engine_launches_total counts each launch under its padded batch
-    and run length: one batch-1 program serves steps 1, 4 and 16."""
+    """dpow_engine_launches_total counts each launch under the rows its grid
+    visits and its run length: a lone request, the self-test's one row
+    among them, launches 1 row of the one max_batch program at steps 1, 4
+    and 16, and dpow_engine_rows_skipped_total takes the max_batch - 1 pad
+    rows each such launch keeps out of its grid."""
 
     async def run():
         b = make_pallas_backend(warm_shapes=True, max_batch=4, run_steps=16)
         before = {s: b._m_launches.value("1", str(s)) for s in (1, 4, 16)}
         warm_before = b._m_launches.value("4", "1")
+        skipped = b._m_rows_skipped.value()
         await b.setup()
         await b._warm_task
         assert b._m_launches.value("1", "1") == before[1] + 1  # self-test
-        assert b._m_launches.value("4", "1") == warm_before + 1  # warm task
+        assert b._m_launches.value("4", "1") == warm_before  # nothing to warm
+        assert b._m_rows_skipped.value() == skipped + 3
+        def lone_launches():
+            return sum(b._m_launches.value("1", str(s)) for s in (1, 4, 16))
+
         for steps in (4, 16):
             h = random_hash()
             d = _difficulty_for_steps(b, steps)
+            launched, skipped = lone_launches(), b._m_rows_skipped.value()
             nc.validate_work(h, await b.generate(WorkRequest(h, d)), d)
+            lone = lone_launches() - launched
+            assert lone > 0
+            assert b._m_rows_skipped.value() == skipped + 3 * lone
         for steps in (4, 16):
             assert b._m_launches.value("1", str(steps)) > before[steps]
-        assert b._m_programs.value() == 2
+        assert b._m_programs.value() == 1
         await b.close()
 
     asyncio.run(asyncio.wait_for(run(), 120))

@@ -59,6 +59,10 @@ def _all_windows(kw) -> np.int32:
     return pallas_kernel.window_count(nblocks, nblocks)
 
 
+def _all_rows(rows) -> np.int32:
+    return pallas_kernel.row_count(len(rows), len(rows))
+
+
 def _base(row) -> int:
     return (int(row[search.BASE_HI]) << 32) | int(row[search.BASE_LO])
 
@@ -74,16 +78,21 @@ def _fan_stack(rows, n: int, chunk_per_shard: int) -> np.ndarray:
     return stacked
 
 
-def gang_chunk_batch(impl, rows, *, chunk_per_shard, n_devices=None, **kw):
-    """One ganged window launch via either implementation → offsets[B]. The
-    fan returns each device's local offset; the min-offset election here is
-    the one the engine makes on the host (_apply_fan_rows)."""
+def gang_chunk_batch(impl, rows, *, chunk_per_shard, n_devices=None,
+                     n_rows=None, **kw):
+    """One ganged window launch via either implementation → offsets[B],
+    visiting the first ``n_rows`` rows (all by default). The fan returns
+    each device's local offset; the min-offset election here is the one the
+    engine makes on the host (_apply_fan_rows)."""
     devices = _devs(n_devices)
     windows = _all_windows(kw)
+    live = _all_rows(rows) if n_rows is None else pallas_kernel.row_count(
+        n_rows, len(rows))
     if impl == "fan":
         n = len(devices)
         local = fan_search_devices(
-            _fan_stack(rows, n, chunk_per_shard), windows, devices=devices,
+            _fan_stack(rows, n, chunk_per_shard), live, windows,
+            devices=devices,
             chunk_per_shard=chunk_per_shard, **kw
         )
         glob = [
@@ -95,7 +104,7 @@ def gang_chunk_batch(impl, rows, *, chunk_per_shard, n_devices=None, **kw):
     mesh = make_mesh(devices)
     return np.asarray(
         sharded_search_chunk_batch(
-            replicate_params(rows, mesh), windows, mesh=mesh,
+            replicate_params(rows, mesh), live, windows, mesh=mesh,
             chunk_per_shard=chunk_per_shard, **kw
         )
     )
@@ -257,11 +266,52 @@ def test_batch_rows_on_partial_gang(gang):
         m = make_mesh(jax.devices(), batch_shards=4)
         out = np.asarray(
             sharded_search_chunk_batch(
-                replicate_params(rows, m), pallas_kernel.window_count(1, 1),
+                replicate_params(rows, m), _all_rows(rows),
+                pallas_kernel.window_count(1, 1),
                 mesh=m, chunk_per_shard=CHUNK,
             )
         )
     assert all(int(o) <= 3 for o in out)
+
+
+@pytest.mark.parametrize("n_rows", [1, 3])
+def test_gang_runtime_row_count_visits_only_live_rows(gang, n_rows):
+    """A chunked gang launch passes its live row count to the kernel: the
+    rows it visits return what a launch of every row returns, the rest read
+    SENTINEL."""
+    sub, it = 8, 2
+    chunk = sub * 128 * it
+    easier = 0xFF00000000000000  # ~1 in 256 nonces: every row hits
+    p = np.stack([
+        search.pack_params(secrets.token_bytes(32), easier, 1000 * i)
+        for i in range(4)
+    ])
+    kw = dict(kernel="pallas", sublanes=sub, iters=it, interpret=True)
+    full = gang_chunk_batch(gang, p, chunk_per_shard=chunk, **kw)
+    part = gang_chunk_batch(gang, p, chunk_per_shard=chunk, n_rows=n_rows, **kw)
+    assert SENTINEL not in [int(o) for o in full]
+    np.testing.assert_array_equal(part[:n_rows], full[:n_rows])
+    assert all(int(o) == SENTINEL for o in part[n_rows:])
+
+
+def test_batch_sharded_mesh_masks_shards_past_the_row_count():
+    """On a (batch=2, nonce=n/2) mesh a row count of 1 leaves the second
+    batch shard no live row: its kernel still visits one row (a grid needs
+    one), and the mesh reads SENTINEL for it."""
+    sub, it = 8, 2
+    chunk = sub * 128 * it
+    m = make_mesh(jax.devices(), batch_shards=2)
+    easier = 0xFF00000000000000
+    p = np.stack([
+        search.pack_params(secrets.token_bytes(32), easier, 0) for _ in range(4)
+    ])
+    out = np.asarray(sharded_search_chunk_batch(
+        replicate_params(p, m), pallas_kernel.row_count(1, 4),
+        pallas_kernel.window_count(1, 1), mesh=m, chunk_per_shard=chunk,
+        kernel="pallas", sublanes=sub, iters=it, interpret=True,
+    ))
+    assert int(out[0]) != SENTINEL
+    assert [int(o) for o in out[1:]] == [SENTINEL] * 3
 
 
 def test_gang_search_run_to_solution(gang):
@@ -431,7 +481,8 @@ def test_multihost_mesh_single_process_runs_search():
     p = _params(h, diff, base)
     out = np.asarray(
         sharded_search_chunk_batch(
-            replicate_params(p, mesh), pallas_kernel.window_count(1, 1),
+            replicate_params(p, mesh), _all_rows(p),
+            pallas_kernel.window_count(1, 1),
             mesh=mesh, chunk_per_shard=CHUNK,
         )
     )

@@ -66,13 +66,14 @@ def test_search_chunk_batch_matches_single():
         assert batch[i] == single
 
 
+ONE_ROW = pallas_kernel.row_count(1, 1)
 ONE_WINDOW = pallas_kernel.window_count(1, 1)
 
 
 def _pallas_one_row(params, **kw) -> int:
     """One row through the batched kernel at B = 1, one window."""
     return int(pallas_kernel.pallas_search_chunk_batch(
-        params[None], ONE_WINDOW, interpret=True, **kw)[0])
+        params[None], ONE_ROW, ONE_WINDOW, interpret=True, **kw)[0])
 
 
 def test_pallas_interpret_matches_jnp():
@@ -90,7 +91,8 @@ def test_pallas_interpret_batch():
     n = pallas_kernel.chunk_size(8, 8)
     got = np.asarray(
         pallas_kernel.pallas_search_chunk_batch(
-            jnp.asarray(params), ONE_WINDOW, sublanes=8, iters=8, interpret=True
+            jnp.asarray(params), pallas_kernel.row_count(3, 3), ONE_WINDOW,
+            sublanes=8, iters=8, interpret=True,
         )
     )
     for i in range(3):
@@ -123,6 +125,7 @@ def test_pallas_kernel_traces_the_tile_body_once_per_group():
     sublanes = 32
     kernel = pallas_kernel.pallas_search_chunk_batch.trace(
         jax.ShapeDtypeStruct((1, search.PARAMS_LEN), jnp.uint32),
+        jax.ShapeDtypeStruct((), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.int32),
         sublanes=sublanes, iters=1024, nblocks=8, group=8, unroll=True,
     ).jaxpr
@@ -172,7 +175,8 @@ def test_pallas_interpret_multiblock_matches_single_window():
     params = np.stack([search.pack_params(h, EASY, base=123) for h in hashes])
     got = np.asarray(
         pallas_kernel.pallas_search_chunk_batch(
-            jnp.asarray(params), pallas_kernel.window_count(nb, nb),
+            jnp.asarray(params), pallas_kernel.row_count(2, 2),
+            pallas_kernel.window_count(nb, nb),
             sublanes=sub, iters=it, nblocks=nb, group=grp, interpret=True,
         )
     )
@@ -185,7 +189,7 @@ def test_pallas_interpret_multiblock_sentinel_when_dry():
     params = np.stack([search.pack_params(bytes(32), (1 << 64) - 1, base=0)])
     got = np.asarray(
         pallas_kernel.pallas_search_chunk_batch(
-            jnp.asarray(params), pallas_kernel.window_count(3, 3),
+            jnp.asarray(params), ONE_ROW, pallas_kernel.window_count(3, 3),
             sublanes=8, iters=4, nblocks=3, interpret=True,
         )
     )
@@ -222,8 +226,9 @@ def test_pallas_runtime_window_count_matches_the_static_grid():
     bound = counts[-1]
     fn = pallas_kernel.pallas_search_chunk_batch
     got, traced = {}, []
+    rows = pallas_kernel.row_count(len(params), len(params))
     for n in counts:
-        got[n] = np.asarray(fn(params, pallas_kernel.window_count(n, bound),
+        got[n] = np.asarray(fn(params, rows, pallas_kernel.window_count(n, bound),
                                nblocks=bound, **geometry))
         traced.append(fn._cache_size())
     assert traced[0] == traced[1] == traced[2], "a window count traced again"
@@ -247,6 +252,51 @@ def test_pallas_window_count_outside_the_bound_is_refused(windows):
         pallas_kernel.window_count(windows, 32)
     count = pallas_kernel.window_count(32, 32)
     assert count.dtype == np.int32 and int(count) == 32
+
+
+@pytest.mark.parametrize("rows", [1, 3, 5])
+def test_pallas_runtime_row_count_matches_a_full_launch(rows):
+    """One program of B = 5 rows, its row count a runtime grid bound beside
+    the window count, at 1x, 4x and 16x nblocks windows: each visited row
+    returns what a launch of exactly those rows returns, the rows past the
+    count read SENTINEL, and no row or window count traces again."""
+    sub, it, nb = 8, 2, 2
+    window = pallas_kernel.chunk_size(sub, it)
+    counts = (nb, 4 * nb, 16 * nb)
+    bound = counts[-1]
+    hard = 0xFFFF800000000000  # ~1 in 32768 nonces
+    _, late = _late_hit_row(hard, counts[1] * window, counts[2] * window)
+    easier = 0xFF00000000000000  # ~1 in 256 nonces: hits in the first window
+    easy = [search.pack_params(RNG.bytes(32), easier, base=7 * i) for i in range(4)]
+    params = jnp.asarray(np.stack([easy[0], late, easy[1], easy[2], easy[3]]))
+    batch = len(params)
+    geometry = dict(sublanes=sub, iters=it, nblocks=bound, interpret=True)
+    fn = pallas_kernel.pallas_search_chunk_batch
+    fn(params, pallas_kernel.row_count(batch, batch),
+       pallas_kernel.window_count(1, bound), **geometry)
+    traced = fn._cache_size()
+    got = {
+        n: np.asarray(fn(params, pallas_kernel.row_count(rows, batch),
+                         pallas_kernel.window_count(n, bound), **geometry))
+        for n in counts
+    }
+    assert fn._cache_size() == traced, "a row or window count traced again"
+    for n in counts:
+        want = fn(params[:rows], pallas_kernel.row_count(rows, rows),
+                  pallas_kernel.window_count(n, bound), **geometry)
+        np.testing.assert_array_equal(got[n][:rows], np.asarray(want))
+        assert int(got[n][0]) != int(search.SENTINEL)
+        assert all(int(o) == int(search.SENTINEL) for o in got[n][rows:])
+
+
+@pytest.mark.parametrize("rows", [0, 6, -1])
+def test_pallas_row_count_outside_the_batch_is_refused(rows):
+    """A count past the program's batch (rows that do not exist) or below 1
+    (a grid that never clears the result) fails on the host."""
+    with pytest.raises(ValueError, match="outside"):
+        pallas_kernel.row_count(rows, 5)
+    count = pallas_kernel.row_count(5, 5)
+    assert count.dtype == np.int32 and int(count) == 5
 
 
 # -- device-resident run loop (ops/runloop.py) ---------------------------

@@ -59,21 +59,24 @@ def _params(batch, sharding):
     return jax.ShapeDtypeStruct((batch, PARAMS_LEN), jnp.uint32, sharding=sharding)
 
 
-def _windows(sharding):
+def _count(sharding):
+    """A runtime grid bound: a row or window count, an int32 operand."""
     return jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
 
 
 @pytest.mark.parametrize("batch", [1, 16])
 @pytest.mark.parametrize("windows", ["loop", "runtime"])
 def test_pallas_chunk_batch_compiles(one_chip, batch, windows):
-    """Every launch's window count is an int32 operand. ``loop``: the run
-    loop's per-window program, its bound nblocks. ``runtime``: the engine's
-    chunked launch, its bound run_steps (16) launches of nblocks windows."""
+    """Every launch's row and window counts are int32 operands. ``loop``:
+    the run loop's per-window program, its window bound nblocks.
+    ``runtime``: the engine's chunked launch, its window bound run_steps
+    (16) launches of nblocks windows."""
     geometry = dict(GEOMETRY)
     if windows == "runtime":
         geometry["nblocks"] *= 16
     compiled = pallas_kernel.pallas_search_chunk_batch.lower(
-        _params(batch, one_chip), _windows(one_chip), unroll=True, **geometry
+        _params(batch, one_chip), _count(one_chip), _count(one_chip),
+        unroll=True, **geometry,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -109,7 +112,8 @@ def test_mesh_gang_compiles_on_four_chips(topo, monkeypatch, bound):
         chunk *= steps
     compiled = sharded_search_chunk_batch.lower(
         _params(16, NamedSharding(mesh, P(BATCH_AXIS, None))),
-        _windows(NamedSharding(mesh, P())),
+        _count(NamedSharding(mesh, P())),
+        _count(NamedSharding(mesh, P())),
         mesh=mesh, chunk_per_shard=chunk, kernel="pallas", **geometry,
     ).compile()
     text = compiled.as_text()

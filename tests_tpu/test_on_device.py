@@ -65,8 +65,8 @@ def test_pallas_matches_xla_scanner_on_device(tpu_device):
     chunk = sub * 128 * it
     params = np.stack([search.pack_params(h, 0xFFF0000000000000, base)])
     pall = pallas_kernel.pallas_search_chunk_batch(
-        jnp.asarray(params), pallas_kernel.window_count(1, 1),
-        sublanes=sub, iters=it,
+        jnp.asarray(params), pallas_kernel.row_count(1, 1),
+        pallas_kernel.window_count(1, 1), sublanes=sub, iters=it,
     )
     xla = search.search_chunk_batch(jnp.asarray(params), chunk_size=chunk)
     assert int(np.asarray(pall)[0]) == int(np.asarray(xla)[0])
@@ -87,7 +87,8 @@ def test_pallas_multiblock_early_exit_on_device(tpu_device):
     diff = _plant(h, base + offset)
     params = np.stack([search.pack_params(h, diff, base)])
     out = pallas_kernel.pallas_search_chunk_batch(
-        jnp.asarray(params), pallas_kernel.window_count(nb, nb),
+        jnp.asarray(params), pallas_kernel.row_count(1, 1),
+        pallas_kernel.window_count(nb, nb),
         sublanes=sub, iters=it, nblocks=nb, group=4,
     )
     got = int(np.asarray(out)[0])
@@ -142,7 +143,8 @@ def test_pallas_runtime_window_count_on_device(tpu_device):
     got, traced = {}, []
     for n in counts:
         got[n] = np.asarray(fn(
-            params, pallas_kernel.window_count(n, counts[-1]),
+            params, pallas_kernel.row_count(len(rows), len(rows)),
+            pallas_kernel.window_count(n, counts[-1]),
             nblocks=counts[-1], **geometry))
         traced.append(fn._cache_size())
     assert traced[0] == traced[1] == traced[2]
@@ -166,7 +168,8 @@ def test_flagship_geometry_finds_and_validates(tpu_device):
     diff = 0xFFFFF00000000000  # ~2^20 expected: well inside one dispatch
     params = np.stack([search.pack_params(h, diff, base)])
     out = pallas_kernel.pallas_search_chunk_batch(
-        jnp.asarray(params), pallas_kernel.window_count(4, 4),
+        jnp.asarray(params), pallas_kernel.row_count(1, 1),
+        pallas_kernel.window_count(4, 4),
         sublanes=32, iters=1024, nblocks=4, group=8,
     )
     got = int(np.asarray(out)[0])
@@ -220,7 +223,8 @@ def test_widened_grid_deep_hit_on_device(tpu_device):
     diff = values[argmax]  # unique hit in range, by construction
     params = np.stack([search.pack_params(h, diff, base)])
     out = pallas_kernel.pallas_search_chunk_batch(
-        jnp.asarray(params), pallas_kernel.window_count(nb, nb),
+        jnp.asarray(params), pallas_kernel.row_count(1, 1),
+        pallas_kernel.window_count(nb, nb),
         sublanes=sub, iters=it, nblocks=nb, group=4,
     )
     assert int(np.asarray(out)[0]) == argmax
@@ -228,7 +232,8 @@ def test_widened_grid_deep_hit_on_device(tpu_device):
 
 def test_difficulty_zero_pad_rows_cost_nothing_and_report_zero(tpu_device):
     """Difficulty-0 rows (the engine's batch padding) must hit at offset 0 —
-    the padding contract the two-shape warm design relies on."""
+    the padding contract of every grid that visits its pads (persistent
+    mode, the XLA path)."""
     import jax.numpy as jnp
 
     from tpu_dpow.ops import pallas_kernel, search
@@ -239,7 +244,8 @@ def test_difficulty_zero_pad_rows_cost_nothing_and_report_zero(tpu_device):
     params = np.stack([real] + [pad] * 7)
     out = np.asarray(
         pallas_kernel.pallas_search_chunk_batch(
-            jnp.asarray(params), pallas_kernel.window_count(8, 8),
+            jnp.asarray(params), pallas_kernel.row_count(8, 8),
+            pallas_kernel.window_count(8, 8),
             sublanes=8, iters=16, nblocks=8, group=8,
         )
     )
@@ -273,6 +279,7 @@ def test_sharded_pallas_path_on_device(tpu_device):
 
     out = sharded_search_chunk_batch(
         replicate_params(params, mesh),
+        pallas_kernel.row_count(1, 1),
         pallas_kernel.window_count(nblocks, nblocks),
         mesh=mesh, chunk_per_shard=chunk, kernel="pallas",
         sublanes=sublanes, iters=iters, nblocks=nblocks, group=8,
@@ -297,8 +304,8 @@ def test_sharded_pallas_path_on_device(tpu_device):
 @pytest.mark.parametrize("gang", ["fan", "mesh"])
 def test_gang_runtime_window_count_on_device(gang):
     """The engine's chunked fan (devices=1) and mesh (mesh_devices=1)
-    launches: one program per batch, the window count a runtime operand,
-    at 1x, 4x and 16x nblocks windows return row by row what the XLA
+    launches: one program, the window count a runtime operand, at 1x, 4x
+    and 16x nblocks windows return row by row what the XLA
     scanner returns over the same span — a row planted in the last window,
     a dry row, difficulty-0 pads."""
     from tpu_dpow.backend.jax_backend import JaxWorkBackend
@@ -310,7 +317,7 @@ def test_gang_runtime_window_count_on_device(gang):
         sublanes=sub, iters=it, nblocks=nb, group=4, run_steps=runs[-1],
         **gang_kw,
     )
-    assert b.kernel == "pallas" and b._program(4, 4) == (4, None)
+    assert b.kernel == "pallas" and b._program(4, 4) == (b.max_batch, None)
     rows, h, base, offset, diff = _runtime_count_rows(sub, it, nb * runs[-1])
     window = sub * 128 * it
     for steps in runs:
@@ -329,8 +336,50 @@ def test_gang_runtime_window_count_on_device(gang):
     assert hit <= base + offset and _plant(h, hit) >= diff
 
 
+@pytest.mark.parametrize("path", ["plain", "fan", "mesh"])
+def test_runtime_row_count_on_device(path):
+    """The engine's one chunked program (max_batch 16 rows) launched with
+    1, 5 and 16 live rows at 1x, 4x and 16x nblocks windows: each live row
+    returns what the XLA scanner returns over the same span — a row
+    planted in the last window, a dry row, difficulty-0 pads, easy rows."""
+    from tpu_dpow.backend.jax_backend import JaxWorkBackend
+    from tpu_dpow.ops import search
+
+    sub, it, nb, runs = 8, 8, 2, (1, 4, 16)
+    gang_kw = {"plain": {}, "fan": {"devices": 1}, "mesh": {"mesh_devices": 1}}
+    b = JaxWorkBackend(
+        sublanes=sub, iters=it, nblocks=nb, group=4, run_steps=runs[-1],
+        max_batch=16, **gang_kw[path],
+    )
+    assert b.kernel == "pallas" and b._program(1, 4) == (16, None)
+    planted, h, base, offset, diff = _runtime_count_rows(sub, it, nb * runs[-1])
+    easy = [
+        search.pack_params(secrets.token_bytes(32), 0xFFF0000000000000,
+                           secrets.randbits(64))
+        for _ in range(12)
+    ]
+    rows = np.concatenate([planted, np.stack(easy)])
+    window = sub * 128 * it
+    for steps in runs:
+        want = _reference_offsets(rows, steps * nb * window)
+        for n_rows in (1, 5, 16):
+            lo, hi = b._launch(rows[:n_rows], steps)
+            lo, hi = np.asarray(lo).reshape(-1), np.asarray(hi).reshape(-1)
+            assert lo.shape == (n_rows,)
+            for i in range(n_rows):
+                got = (int(hi[i]) << 32) | int(lo[i])
+                if want[i] == 0xFFFFFFFF:
+                    assert got == (1 << 64) - 1, (steps, n_rows, i)
+                else:
+                    row_base = (int(rows[i][search.BASE_HI]) << 32) | int(
+                        rows[i][search.BASE_LO])
+                    assert got == row_base + int(want[i]), (steps, n_rows, i)
+    hit = (int(hi[0]) << 32) | int(lo[0])
+    assert hit <= base + offset and _plant(h, hit) >= diff
+
+
 def test_backend_run_mode_and_warm_shapes_on_device():
-    """The production defaults (widened runs + two-shape warming) through
+    """The production defaults (widened runs + one-program warming) through
     generate(): singles and a batch burst, all hashlib-valid."""
     import asyncio
 
@@ -355,8 +404,8 @@ def test_backend_run_mode_and_warm_shapes_on_device():
         works = await asyncio.gather(*(b.generate(r) for r in reqs))
         for r, w in zip(reqs, works):
             nc.validate_work(r.block_hash, w, easy)
-        # The plain Pallas path: one program per batch, every run length.
-        assert b._warm == {(1, None), (4, None)} and not b.cold_shapes()
+        # The plain Pallas path: one program, every row count and run length.
+        assert b._warm == {(4, None)} and not b.cold_shapes()
         await b.close()
 
     asyncio.run(run())

@@ -572,8 +572,12 @@ class JaxWorkBackend(WorkBackend):
             "dpow_engine_rungs", "Distinct difficulty rungs with live demand")
         self._m_launches = reg.counter(
             "dpow_engine_launches_total",
-            "Device launches submitted, by padded batch and run length "
-            "(windows of nblocks)", ("batch", "steps"))
+            "Device launches submitted, by rows the launch's grid visits "
+            "and run length (windows of nblocks)", ("batch", "steps"))
+        self._m_rows_skipped = reg.counter(
+            "dpow_engine_rows_skipped_total",
+            "Padding rows a chunked Pallas launch's runtime row count kept "
+            "out of its grid")
         self._m_programs = reg.gauge(
             "dpow_engine_launch_programs",
             "Compiled launch programs the engine holds (its warm set)")
@@ -711,7 +715,8 @@ class JaxWorkBackend(WorkBackend):
             # With warming ON (TPU), setup() returns after the single
             # self-test compile; the rest of the ladder — max_batch, and
             # where programs are keyed by run length (_program) the
-            # (1, steps) rungs — compiles in the background. Each costs
+            # (1, steps) rungs — compiles in the background (on the chunked
+            # Pallas path the self-test ran the one program). Each costs
             # seconds, and a client blocked in setup() serves nothing; a
             # request arriving before its rung is warm just runs at the
             # largest warmed step count (more round trips, still correct —
@@ -1359,7 +1364,8 @@ class JaxWorkBackend(WorkBackend):
         self._ensure_watchdog()
 
     def _batch_sizes(self) -> list:
-        """The padded batch sizes the engine may emit (ascending).
+        """The padded batch sizes the engine may emit (ascending). On the
+        chunked Pallas path every size runs one program (_program).
 
         With shape warming on (TPU) there are exactly TWO: singleton and
         max_batch. Difficulty-0 padding rows are free on the Pallas path
@@ -1423,14 +1429,19 @@ class JaxWorkBackend(WorkBackend):
                 exc_info=True,
             )
 
+    def _rows_at_run_time(self) -> bool:
+        """A chunked Pallas launch — plain, fan or mesh — takes its row and
+        window counts at run time: one program, at max_batch rows, serves
+        every launch (_launch pads to it)."""
+        return self.kernel == "pallas" and self.run_mode == "chunked"
+
     def _program(self, b: int, steps: int) -> tuple:
         """The compiled launch program a (batch, steps) launch runs, as the
-        warm set keys it. A chunked Pallas launch — plain, fan or mesh —
-        takes its window count at run time, so one program per batch serves
-        every run length: (b, None). Persistent mode's while_loop and the
-        XLA scanner compile each run length: (b, steps)."""
-        if self.kernel == "pallas" and self.run_mode == "chunked":
-            return b, None
+        warm set keys it: (max_batch, None) for every chunked Pallas launch
+        (_rows_at_run_time). Persistent mode's while_loop and the XLA
+        scanner compile each batch and run length: (b, steps)."""
+        if self._rows_at_run_time():
+            return self.max_batch, None
         return b, steps
 
     def _mark_warm(self, b: int, steps: int) -> None:
@@ -1451,9 +1462,13 @@ class JaxWorkBackend(WorkBackend):
 
         Falls back to fewer steps (more round trips) or a smaller batch
         (jobs beyond it wait one engine pass) rather than stalling every
-        active request behind a cold compile.
+        active request behind a cold compile. On the chunked Pallas path
+        the shape is the demand itself: its one program runs any rows and
+        run length.
         """
         want = min(max(njobs, 1), self.max_batch)
+        if self._rows_at_run_time():
+            return want, steps_want  # one program serves every shape
         b_want = next(b for b in self._batch_sizes() if b >= want)
         if not self.warm_shapes or not self._warm:
             # Warming off (CPU default) or nothing warmed yet (generate()
@@ -1535,7 +1550,10 @@ class JaxWorkBackend(WorkBackend):
         block registered: the launch reads dead zeros and just runs).
         ``devices`` pins the launch to a fan subset (degraded width /
         re-admission probes); None = the engine's full complement."""
-        self._m_launches.inc(1, str(params_batch.shape[-2]), str(steps))
+        rows = params_batch.shape[-2]
+        self._m_launches.inc(1, str(rows), str(steps))
+        if self._rows_at_run_time():
+            self._m_rows_skipped.inc(self.max_batch - rows)
         if self._executor is None:
             import concurrent.futures
 
@@ -1627,8 +1645,10 @@ class JaxWorkBackend(WorkBackend):
         """One blocking batched device launch (called via to_thread).
 
         Returns (lo, hi) uint32[B] — absolute winning nonces per row,
-        all-ones where the scanned span held no solution (padding rows
-        short-circuit via difficulty 0; their results are discarded).
+        all-ones where the scanned span held no solution. A chunked Pallas
+        launch pads its B rows to max_batch for its one program and visits
+        only the B (_pad_rows); elsewhere padding rows arrive packed and
+        short-circuit via difficulty 0, their results discarded.
         ``steps`` > 1 widens the
         launch to ``steps`` consecutive windows in the same single dispatch
         (bigger ``nblocks`` grid / chunk), so the whole span costs one
@@ -1643,13 +1663,19 @@ class JaxWorkBackend(WorkBackend):
         if self.run_mode == "persistent":
             return self._launch_persistent(params_batch, steps, slot, devices)
         nblocks = self.nblocks * steps
-        # The launch's program (_program): on Pallas one per batch, the
-        # window count a runtime operand under the static bound of
-        # run_steps; the XLA scanner's chunk is static, so there the bound
-        # is this launch.
+        # The launch's program (_program): on Pallas one, the row and window
+        # counts runtime operands under the static max_batch and run_steps;
+        # the XLA scanner's batch and chunk are static, so there the bounds
+        # are this launch.
+        n_rows = params_batch.shape[-2]
         bound = self.nblocks * (self.run_steps if self.kernel == "pallas" else steps)
         windows = pallas_kernel.window_count(nblocks, bound)
         chunk_bound = pallas_kernel.chunk_size(self.sublanes, self.iters) * bound
+        if self._rows_at_run_time():
+            rows = pallas_kernel.row_count(n_rows, self.max_batch)
+            params_batch = self._pad_rows(params_batch, self.max_batch)
+        else:
+            rows = pallas_kernel.row_count(n_rows, n_rows)
         if self.fan is not None:
             from ..parallel import fan_search_devices
 
@@ -1663,6 +1689,7 @@ class JaxWorkBackend(WorkBackend):
             with obs.span("dpow.launch.wait"):
                 offs = fan_search_devices(
                     params_batch,
+                    rows,
                     windows,
                     devices=devs,
                     chunk_per_shard=chunk_bound,
@@ -1672,7 +1699,8 @@ class JaxWorkBackend(WorkBackend):
                     nblocks=bound,
                     group=self.group,
                     interpret=self.interpret,
-                )
+                )[:, :n_rows]
+            params_batch = params_batch[:, :n_rows]
             flat_p = params_batch.reshape(-1, search.PARAMS_LEN)
             lo, hi = self._offsets_to_nonces(flat_p, offs.reshape(-1))
             # Per-device absolute nonces [n_dev, B] (all-ones where that
@@ -1686,6 +1714,7 @@ class JaxWorkBackend(WorkBackend):
                 offs = np.asarray(
                     sharded_search_chunk_batch(
                         replicate_params(params_batch, self.mesh),
+                        rows,
                         windows,
                         mesh=self.mesh,
                         chunk_per_shard=chunk_bound,
@@ -1696,8 +1725,8 @@ class JaxWorkBackend(WorkBackend):
                         group=self.group,
                         interpret=self.interpret,
                     )
-                )
-            return self._offsets_to_nonces(params_batch, offs)
+                )[:n_rows]
+            return self._offsets_to_nonces(params_batch[:n_rows], offs)
         # The plain path, phase by phase on the profiler's clock: the
         # (asynchronous) dispatch, the device run, the host readback.
         with obs.span("dpow.launch.dispatch"):
@@ -1705,6 +1734,7 @@ class JaxWorkBackend(WorkBackend):
             if self.kernel == "pallas":
                 out = pallas_kernel.pallas_search_chunk_batch(
                     pj,
+                    rows,
                     windows,
                     sublanes=self.sublanes,
                     iters=self.iters,
@@ -1717,8 +1747,8 @@ class JaxWorkBackend(WorkBackend):
         with obs.span("dpow.launch.wait"):
             out.block_until_ready()
         with obs.span("dpow.launch.readback"):
-            offs = np.asarray(out)
-        return self._offsets_to_nonces(params_batch, offs)
+            offs = np.asarray(out)[:n_rows]
+        return self._offsets_to_nonces(params_batch[:n_rows], offs)
 
     def _launch_hook_indices(self, devices: Optional[tuple]) -> tuple:
         """PHYSICAL fan indices this launch touches — the chaos seam's
@@ -1806,21 +1836,31 @@ class JaxWorkBackend(WorkBackend):
     _PAD_ROW = None  # lazily built difficulty-0 padding row
 
     def _pack(self, jobs: list, b: int) -> np.ndarray:
-        """Fixed-shape batch: active jobs + difficulty-0 padding.
+        """Fixed-shape batch: active jobs + difficulty-0 padding (_pad_rows).
+        Pad results are discarded by the engine (only the first len(jobs)
+        rows are read back)."""
+        rows = np.array([j.params for j in jobs], dtype=np.uint32)
+        return self._pad_rows(rows.reshape(-1, search.PARAMS_LEN), b)
 
-        Difficulty 0 makes a padding row "hit" at offset 0, so the
-        persistent-kernel grid's per-row found flag skips all its windows
-        and the in-window early exit fires after one tile group — an
-        unreachable-difficulty pad would instead scan the launch's whole
-        widened span every pass. Pad results are discarded by the engine
-        (only the first len(jobs) rows are read back).
+    @staticmethod
+    def _pad_rows(rows: np.ndarray, b: int) -> np.ndarray:
+        """uint32[..., n, 12] rows → uint32[..., b, 12], difficulty-0 rows
+        appended.
+
+        Difficulty 0 makes a padding row "hit" at offset 0, so wherever a
+        grid does visit it (persistent mode, the XLA scanner) the per-row
+        found flag skips all its windows and the in-window early exit fires
+        after one tile group — an unreachable-difficulty pad would instead
+        scan the launch's whole widened span every pass. A chunked Pallas
+        launch's runtime row count keeps its pads out of the grid.
         """
         if JaxWorkBackend._PAD_ROW is None:
             JaxWorkBackend._PAD_ROW = search.pack_params(bytes(32), 0, 0)
-        out = np.empty((b, search.PARAMS_LEN), dtype=np.uint32)
-        for i in range(b):
-            out[i] = jobs[i].params if i < len(jobs) else JaxWorkBackend._PAD_ROW
-        return out
+        pad = np.broadcast_to(
+            JaxWorkBackend._PAD_ROW,
+            rows.shape[:-2] + (b - rows.shape[-2], search.PARAMS_LEN),
+        )
+        return np.concatenate([rows, pad], axis=-2)
 
     # -- device fan (devices >= 1) ----------------------------------------
 

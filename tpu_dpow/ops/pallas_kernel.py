@@ -106,7 +106,7 @@ def _search_core(
 def _kernel_blocks(
     params_ref, out_ref, *, sublanes: int, iters: int, unroll: bool, group: int
 ):
-    """The search kernel: grid = (B, windows); one dispatch, early exit.
+    """The search kernel: grid = (rows, windows); one dispatch, early exit.
 
     The SMEM output is shared across sequential grid steps, so it doubles as
     the found-flag: once a block writes a real offset for request b, every
@@ -115,14 +115,18 @@ def _kernel_blocks(
     geometry sweep exposed (SURVEY.md §7 hard part #3: "dispatch overhead
     ≈ 0 is load-bearing") while keeping in-launch cancellation granularity
     at one window.
+
+    The first grid step clears every one of the B output words, so rows
+    past the runtime row count, which the grid never visits, read SENTINEL.
     """
     b = pl.program_id(0)
     g = pl.program_id(1)
     span = np.uint32(sublanes * 128 * iters)
 
-    @pl.when(g == 0)
+    @pl.when((b == 0) & (g == 0))
     def _init():
-        out_ref[b, 0] = jnp.uint32(SENTINEL)
+        for row in range(out_ref.shape[0]):
+            out_ref[row, 0] = jnp.uint32(SENTINEL)
 
     @pl.when(out_ref[b, 0] == SENTINEL)
     def _compute():
@@ -147,6 +151,7 @@ def _default_unroll(interpret: bool) -> bool:
 )
 def pallas_search_chunk_batch(
     params_batch: jnp.ndarray,
+    rows: jnp.ndarray,
     windows: jnp.ndarray,
     *,
     sublanes: int = DEFAULT_SUBLANES,
@@ -158,18 +163,19 @@ def pallas_search_chunk_batch(
 ) -> jnp.ndarray:
     """Batched launch: uint32[B, 12] → uint32[B], one dispatch.
 
-    Batching concurrent requests into a single fixed-shape launch (padded
-    slots get masked upstream by the backend) replaces the reference's
-    one-item-at-a-time POSTs to the native worker
+    Batching concurrent requests into a single fixed-shape launch replaces
+    the reference's one-item-at-a-time POSTs to the native worker
     (reference client/work_handler.py:98-108) without recompiles.
 
-    Each request scans ``windows`` consecutive windows of
-    ``sublanes * 128 * iters`` nonces inside the one dispatch, with early
-    exit between windows — the persistent-kernel mode that amortizes
-    dispatch overhead. ``windows`` (an int32 scalar, from ``window_count``)
-    is a runtime grid bound; ``nblocks`` is its static upper bound, the one
-    the 2^31-offset guard holds. One compiled program per batch size then
-    serves every window count up to ``nblocks``.
+    The grid visits the first ``rows`` of the B rows (an int32 scalar, from
+    ``row_count``); the rest read SENTINEL. Each visited request scans
+    ``windows`` consecutive windows of ``sublanes * 128 * iters`` nonces
+    inside the one dispatch, with early exit between windows — the
+    persistent-kernel mode that amortizes dispatch overhead. ``windows``
+    (an int32 scalar, from ``window_count``) is the grid's other runtime
+    bound; ``nblocks`` is its static upper bound, the one the 2^31-offset
+    guard holds. One compiled program per B then serves every row count up
+    to B and every window count up to ``nblocks``.
     """
     if unroll is None:
         unroll = _default_unroll(interpret)
@@ -185,11 +191,25 @@ def pallas_search_chunk_batch(
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.uint32),
-        grid=(b, windows),
+        grid=(rows, windows),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         interpret=interpret,
     )(params_batch)[:, 0]
+
+
+def row_count(rows: int, batch: int) -> np.int32:
+    """A launch's runtime row count for ``pallas_search_chunk_batch``,
+    checked on the host against the program's static batch ``batch``.
+
+    Inside the program the count is traced, so nothing there can refuse a
+    count past the batch (whose grid would read rows that do not exist) or
+    below 1 (whose grid never runs the step that clears the result). Always
+    an ``np.int32``: a weak-typed Python int would trace a second program.
+    """
+    if not 1 <= rows <= batch:
+        raise ValueError(f"row count {rows} outside [1, {batch}]")
+    return np.int32(rows)
 
 
 def window_count(windows: int, nblocks: int) -> np.int32:

@@ -56,6 +56,7 @@ def check_geometry(
 
 def scan_windows(
     params_batch: jnp.ndarray,
+    rows,
     windows,
     *,
     kernel: str,
@@ -68,18 +69,20 @@ def scan_windows(
     unroll: Optional[bool] = None,
 ) -> jnp.ndarray:
     """One launch's scan: the first ``windows`` of ``nblocks`` windows of
-    ``chunk // nblocks`` nonces per row → uint32[B] lowest valid offsets,
-    SENTINEL where dry.
+    ``chunk // nblocks`` nonces for each of the first ``rows`` rows →
+    uint32[B] lowest valid offsets, SENTINEL where dry.
 
-    ``windows`` is an int32 scalar from ``pallas_kernel.window_count``.
-    'pallas' makes it the kernel's runtime grid bound, so one program per
-    batch size serves every count; 'xla' scans the static ``chunk`` with
-    the fused jnp scanner and drops the hits past ``windows`` windows, so
-    both kernels answer for the same span.
+    ``rows`` and ``windows`` are int32 scalars from
+    ``pallas_kernel.row_count`` and ``pallas_kernel.window_count``.
+    'pallas' makes them the kernel's runtime grid bounds, so one program
+    serves every count, and the rows past ``rows`` read SENTINEL; 'xla'
+    (the CPU fallback) scans every row's static ``chunk`` with the fused
+    jnp scanner and drops the hits past ``windows`` windows, so both
+    kernels answer for the same span of every visited row.
     """
     if kernel == "pallas":
         return pallas_kernel.pallas_search_chunk_batch(
-            params_batch, windows, sublanes=sublanes, iters=iters,
+            params_batch, rows, windows, sublanes=sublanes, iters=iters,
             nblocks=nblocks, group=group, interpret=interpret, unroll=unroll,
         )
     offs = search.search_chunk_batch(params_batch, chunk_size=chunk, unroll=unroll)
@@ -297,11 +300,12 @@ def search_run_batch_controlled(
     if window >= 1 << 31:
         raise ValueError("per-step window must stay below 2^31 nonces")
 
+    rows = pallas_kernel.row_count(params_batch.shape[0], params_batch.shape[0])
     windows = pallas_kernel.window_count(nblocks, nblocks)
 
     def launch(params: jnp.ndarray) -> jnp.ndarray:
         return scan_windows(
-            params, windows, kernel=kernel, chunk=window, sublanes=sublanes,
+            params, rows, windows, kernel=kernel, chunk=window, sublanes=sublanes,
             iters=iters, nblocks=nblocks, group=group, interpret=interpret,
             unroll=unroll,
         )

@@ -5,8 +5,8 @@ Two gang implementations serve the engine: the shard_map mesh
 (mesh_search: ``sharded_search_chunk_batch``, a pmin election on device)
 and the pmap fan (fan_search: ``fan_search_devices`` chunked,
 ``fan_search_run_controlled`` persistent; the host elects the winner).
-Every chunked Pallas launch takes its window count at run time, so each
-compiles one program per batch size. ``sharded_search_run`` and
+Every chunked Pallas launch takes its row and window counts at run time,
+so each compiles one program. ``sharded_search_run`` and
 multihost.py serve no engine path (ROADMAP C6); ROADMAP C3 weighs keeping
 only one gang."""
 

@@ -13,8 +13,9 @@ untouched single-chip window scan (ops/runloop.py ``scan_windows``), so
 the fanned path is bit-identical to the tested single-chip path; only
 placement differs.
 
-  * :func:`fan_search_devices` — one chunked launch per device, its window
-    count a runtime operand under the static bound ``nblocks``;
+  * :func:`fan_search_devices` — one chunked launch per device, its row
+    and window counts runtime operands under the static batch and
+    ``nblocks``;
   * :func:`fan_search_run_controlled` — the persistent launch: each device
     runs the shared device-resident while_loop (ops/runloop.py) with a
     live control channel.
@@ -77,8 +78,8 @@ def _check_fan(
 # pmap callables are cached per static geometry: jax.pmap returns a fresh
 # wrapper each call, and rebuilding it per launch would re-trace on the hot
 # path. Keyed on the device tuple too — a different fan width or device
-# subset is a different compiled program. The window count is an operand,
-# not part of the key: one program per batch size serves every count.
+# subset is a different compiled program. The row and window counts are
+# operands, not part of the key: one program serves every count.
 
 
 @functools.lru_cache(maxsize=None)
@@ -86,20 +87,23 @@ def _fan_devices_fn(
     devices: tuple, chunk_per_shard: int, kernel: str, sublanes: int,
     iters: int, nblocks: int, group: int, interpret: bool,
 ):
-    def dev_fn(p_local: jnp.ndarray, windows: jnp.ndarray) -> jnp.ndarray:
+    def dev_fn(
+        p_local: jnp.ndarray, rows: jnp.ndarray, windows: jnp.ndarray
+    ) -> jnp.ndarray:
         return runloop.scan_windows(
-            p_local, windows, kernel=kernel, chunk=chunk_per_shard,
+            p_local, rows, windows, kernel=kernel, chunk=chunk_per_shard,
             sublanes=sublanes, iters=iters, nblocks=nblocks, group=group,
             interpret=interpret,
         )
 
     return jax.pmap(
-        dev_fn, axis_name=FAN_AXIS, devices=devices, in_axes=(0, None)
+        dev_fn, axis_name=FAN_AXIS, devices=devices, in_axes=(0, None, None)
     )
 
 
 def fan_search_devices(
     stacked_params: np.ndarray,
+    rows,
     windows,
     *,
     devices: Sequence[jax.Device],
@@ -114,10 +118,11 @@ def fan_search_devices(
     """Per-device launch with caller-owned bases: uint32[D,B,12] → uint32[D,B]
     LOCAL offsets (SENTINEL where that device's span is dry).
 
-    Each device scans ``windows`` windows (an int32 scalar from
-    ``pallas_kernel.window_count``) of ``chunk_per_shard // nblocks``
-    nonces from its own rows' bases; ``chunk_per_shard`` is the static
-    bound of ``nblocks`` windows.
+    Each device scans the first ``rows`` rows (an int32 scalar from
+    ``pallas_kernel.row_count``), ``windows`` windows each (an int32
+    scalar from ``pallas_kernel.window_count``) of ``chunk_per_shard //
+    nblocks`` nonces from its own rows' bases; ``chunk_per_shard`` is the
+    static bound of ``nblocks`` windows.
     """
     devs = tuple(devices)
     _check_fan(stacked_params, devs, chunk_per_shard, kernel, sublanes, iters, nblocks)
@@ -125,7 +130,7 @@ def fan_search_devices(
         devs, chunk_per_shard, kernel, sublanes, iters, nblocks, group,
         interpret,
     )
-    return np.asarray(fn(jnp.asarray(stacked_params), windows))
+    return np.asarray(fn(jnp.asarray(stacked_params), rows, windows))
 
 
 @functools.lru_cache(maxsize=None)
@@ -136,11 +141,12 @@ def _fan_controlled_fn(
 ):
     def dev_fn(p_local: jnp.ndarray, active: jnp.ndarray, slot: jnp.ndarray):
         idx = lax.axis_index(FAN_AXIS)
+        rows = pallas_kernel.row_count(p_local.shape[0], p_local.shape[0])
         windows = pallas_kernel.window_count(nblocks, nblocks)
 
         def launch(params: jnp.ndarray) -> jnp.ndarray:
             return runloop.scan_windows(
-                params, windows, kernel=kernel, chunk=chunk_per_shard,
+                params, rows, windows, kernel=kernel, chunk=chunk_per_shard,
                 sublanes=sublanes, iters=iters, nblocks=nblocks, group=group,
                 interpret=interpret,
             )

@@ -78,6 +78,7 @@ _advance_base = search.advance_base_batch
 )
 def sharded_search_chunk_batch(
     params_batch: jnp.ndarray,
+    rows: jnp.ndarray,
     windows: jnp.ndarray,
     *,
     mesh: Mesh,
@@ -91,18 +92,20 @@ def sharded_search_chunk_batch(
 ) -> jnp.ndarray:
     """One ganged multi-chip launch: uint32[B,12] → uint32[B] global offsets.
 
-    Each shard scans ``windows`` windows (an int32 scalar from
-    ``pallas_kernel.window_count``) of ``chunk_per_shard // nblocks``
-    nonces; ``chunk_per_shard`` is the static bound of ``nblocks`` windows,
-    so one program per batch size serves every window count. The ganged
+    Each shard scans the first ``rows`` rows of the batch (an int32 scalar
+    from ``pallas_kernel.row_count``; the rest read SENTINEL), ``windows``
+    windows each (an int32 scalar from ``pallas_kernel.window_count``) of
+    ``chunk_per_shard // nblocks`` nonces; ``chunk_per_shard`` is the
+    static bound of ``nblocks`` windows, so one program serves every row
+    and window count. The ganged
     window is ``span * mesh.shape[NONCE_AXIS]`` nonces with ``span`` the
     shards' common scan, and shard i starts ``i * span`` past the base.
     The returned offset is relative to the request's own base (SENTINEL if
     the whole ganged window is dry), so the host loop advances bases by the
     *global* span exactly as in the single-chip engine.
 
-    kernel='pallas' runs the hand-tiled TPU kernel per shard, ``windows``
-    its runtime grid bound (then chunk_per_shard must equal
+    kernel='pallas' runs the hand-tiled TPU kernel per shard, its row and
+    window counts the runtime grid bounds (then chunk_per_shard must equal
     sublanes*128*iters*nblocks); 'xla' runs the fused jnp scanner (any
     backend — what the CPU-mesh tests and the virtual-device dryrun of
     ``__graft_entry__.py`` exercise). Either way the per-shard scan is ops/runloop.py
@@ -119,27 +122,36 @@ def sharded_search_chunk_batch(
         nblocks=nblocks,
     )
 
-    def shard_fn(p_local: jnp.ndarray, windows: jnp.ndarray) -> jnp.ndarray:
+    def shard_fn(
+        p_local: jnp.ndarray, rows: jnp.ndarray, windows: jnp.ndarray
+    ) -> jnp.ndarray:
         idx = lax.axis_index(NONCE_AXIS).astype(jnp.uint32)
         span = windows.astype(jnp.uint32) * jnp.uint32(chunk_per_shard // nblocks)
+        # This batch shard's rows of the first ``rows``: the kernel's grid
+        # visits at least one, so a shard past the count is masked below.
+        b_local = p_local.shape[0]
+        first = lax.axis_index(BATCH_AXIS) * b_local
+        row = first + lax.iota(jnp.int32, b_local)
         local = runloop.scan_windows(
-            _advance_base(p_local, idx * span), windows, kernel=kernel,
+            _advance_base(p_local, idx * span),
+            jnp.clip(rows - first, 1, b_local), windows, kernel=kernel,
             chunk=chunk_per_shard, sublanes=sublanes, iters=iters,
             nblocks=nblocks, group=group, interpret=interpret,
         )
         # Local offset → offset from the request's own base. SENTINEL
         # (uint32 max) stays above every reachable global offset (< 2^31),
         # so the min-election needs no special casing.
-        glob = jnp.where(local == SENTINEL, SENTINEL, idx * span + local)
+        dry = (local == SENTINEL) | (row >= rows)
+        glob = jnp.where(dry, SENTINEL, idx * span + local)
         return lax.pmin(glob, NONCE_AXIS)
 
     return jax.shard_map(
         shard_fn,
         mesh=mesh,
-        in_specs=(P(BATCH_AXIS, None), P()),
+        in_specs=(P(BATCH_AXIS, None), P(), P()),
         out_specs=P(BATCH_AXIS),
         check_vma=False,  # pmin replicates the result across NONCE_AXIS
-    )(params_batch, windows)
+    )(params_batch, rows, windows)
 
 
 @functools.partial(
@@ -181,11 +193,12 @@ def sharded_search_run(
     """
     n_nonce = mesh.shape[NONCE_AXIS]
     global_chunk = chunk_per_shard * n_nonce
+    rows = pallas_kernel.row_count(params_batch.shape[0], params_batch.shape[0])
     windows = pallas_kernel.window_count(nblocks, nblocks)
 
     def launch(params: jnp.ndarray) -> jnp.ndarray:
         return sharded_search_chunk_batch(
-            params, windows, mesh=mesh, chunk_per_shard=chunk_per_shard,
+            params, rows, windows, mesh=mesh, chunk_per_shard=chunk_per_shard,
             kernel=kernel, sublanes=sublanes, iters=iters, nblocks=nblocks,
             group=group, interpret=interpret,
         )
